@@ -64,14 +64,14 @@ def run_condition(
     envs: Optional[Sequence] = None,
 ):
     """Run one named condition from the paper's baseline set."""
-    cfg, kw = _condition_kwargs(name, envs[0] if envs is not None else env)
+    cfg, kw = condition_kwargs(name, envs[0] if envs is not None else env)
     target = envs if envs is not None else env
     kw = dict(kw, seeds=seeds,
               shuffle=False if envs is not None else shuffle)
     return evaluate.run(cfg, target, budget, **kw)
 
 
-def _condition_kwargs(name: str, env):
+def condition_kwargs(name: str, env):
     """(cfg, evaluate-kwargs) for one named baseline condition."""
     priors = list(warmup_priors())
     k = env.k
@@ -99,7 +99,7 @@ def run_condition_grid(
     """A whole budget grid of one named condition as ONE compiled,
     device-sharded call (sweep fabric) — per budget bit-identical to the
     looped ``run_condition`` it replaces."""
-    cfg, kw = _condition_kwargs(name, env)
+    cfg, kw = condition_kwargs(name, env)
     return sweep.run_grid(cfg, env, budgets, seeds=seeds, shuffle=shuffle,
                           **kw)
 

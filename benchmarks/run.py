@@ -45,6 +45,9 @@ def main(argv=None) -> None:
     ap.add_argument("--only", nargs="*", default=None)
     args = ap.parse_args(argv)
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     import importlib
     # Entries whose module or entrypoint differs from bench_{name}.main().
     MODULES = {"scenario_grid": "scenarios",

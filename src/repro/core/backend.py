@@ -9,9 +9,10 @@ arm through a ``RoutingBackend``. Three implementations ship
                        reference.
   * ``pallas``       — the scoring TPU kernel (``kernels/linucb_score``):
                        requests tiled in rows, all K arms' (d x d)
-                       inverses resident in VMEM. Runs in interpret mode
-                       off-TPU so CPU tests exercise the exact kernel
-                       code path that compiles on hardware.
+                       inverses resident in VMEM. Compiled on a TPU and
+                       run in interpret mode on the CPU
+                       (``kernels.interpret_mode``), so CPU tests exercise
+                       the exact kernel code that compiles for the chip.
   * ``pallas_fused`` — the full step megakernel (``kernels/linucb_step``):
                        score -> hard-ceiling select -> chosen-arm decay +
                        Sherman-Morrison + theta refresh + pacer dual step
@@ -85,19 +86,10 @@ class JnpBackend:
 class PallasBackend:
     name = "pallas"
 
-    def __init__(self, interpret: bool | None = None):
-        # None = auto: compiled on TPU, interpret elsewhere.
-        self._interpret = interpret
-
     def score(self, cfg, hp, theta, A_inv, c_tilde, X, dt, lam) -> Array:
-        interpret = self._interpret
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
         pen = (hp.lambda_c + lam) * c_tilde
         infl = linucb.staleness_inflation(cfg, hp, dt)
-        return linucb_score(
-            X, theta, A_inv, pen, infl, hp.alpha, interpret=interpret
-        )
+        return linucb_score(X, theta, A_inv, pen, infl, hp.alpha)
 
 
 class FusedPallasBackend(PallasBackend):
@@ -134,9 +126,6 @@ class FusedPallasBackend(PallasBackend):
         the ``pacer.enabled`` gate (a frozen pacer changes nothing per
         step, so gating the block result is the same fold).
         """
-        interpret = self._interpret
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
         hp = state.hyper
         cand = pacer_lib.hard_ceiling_mask(
             state.pacer, state.price, state.active)
@@ -150,7 +139,7 @@ class FusedPallasBackend(PallasBackend):
             hp.alpha, hp.gamma, hp.eta, hp.alpha_ema, hp.lambda_bar,
             state.pacer.lam, state.pacer.c_ema, state.pacer.budget,
             t_sel, farm, forced,
-            dt_max=cfg.dt_max, interpret=interpret,
+            dt_max=cfg.dt_max,
         )
 
 

@@ -24,6 +24,11 @@ Array = jax.Array
 # the kernel clamps (identity for every valid gamma).
 GAMMA_FLOOR = 1e-6
 
+# Every contraction here states full f32. On a TPU v5e the batched score
+# einsum at DEFAULT precision strayed up to 3.0e-3 from the f32 kernels
+# (the contract is 1e-4); the CPU computes f32 either way.
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def forgetting_factor(cfg: RouterConfig, hp: HyperParams, dt: Array) -> Array:
     """gamma^dt with a numerical clamp on the exponent.
@@ -52,8 +57,8 @@ def decay_statistics(
 
 def sherman_morrison(A_inv: Array, x: Array) -> Array:
     """Rank-1 inverse update: (A + x x^T)^{-1} from A^{-1} in O(d^2)."""
-    Ax = A_inv @ x                       # (d,)
-    denom = 1.0 + x @ Ax
+    Ax = jnp.matmul(A_inv, x, precision=HIGHEST)        # (d,)
+    denom = 1.0 + jnp.matmul(x, Ax, precision=HIGHEST)
     return A_inv - jnp.outer(Ax, Ax) / denom
 
 
@@ -75,7 +80,7 @@ def rank1_update(
     A = A + jnp.outer(x, x)
     A_inv = sherman_morrison(A_inv, x)
     b = b + r * x
-    theta = A_inv @ b
+    theta = jnp.matmul(A_inv, b, precision=HIGHEST)
     return A, A_inv, b, theta
 
 
@@ -86,7 +91,8 @@ def ucb_variance(
 
     v_a = x^T A_a^{-1} x / max(gamma^{dt_a}, 1/V_max)
     """
-    q = x @ (A_inv @ x)
+    q = jnp.matmul(x, jnp.matmul(A_inv, x, precision=HIGHEST),
+                   precision=HIGHEST)
     q = jnp.maximum(q, 0.0)  # guard tiny negative from f32 round-off
     return q / staleness_inflation(cfg, hp, dt)
 
@@ -103,7 +109,7 @@ def ucb_scores(
 ) -> Array:
     """Eq. 2 scores for every arm (the Pallas linucb_score kernel mirrors
     this math for batched request streams; this is the jnp oracle)."""
-    exploit = theta @ x                                     # (K,)
+    exploit = jnp.matmul(theta, x, precision=HIGHEST)       # (K,)
     v = jax.vmap(lambda Ai, d_: ucb_variance(cfg, hp, Ai, x, d_))(A_inv, dt)
     explore = hp.alpha * jnp.sqrt(v)
     penalty = (hp.lambda_c + lam) * c_tilde
@@ -139,9 +145,10 @@ def ucb_scores_batch(
     depends on lambda and it is elementwise, so row b of the vector path
     is bit-identical to scoring the whole block under scalar ``lam[b]``.
     """
-    exploit = X @ theta.T                                   # (B, K)
-    t = jnp.einsum("bd,kde->bke", X, A_inv)
-    quad = jnp.maximum(jnp.einsum("bke,be->bk", t, X), 0.0)
+    exploit = jnp.matmul(X, theta.T, precision=HIGHEST)     # (B, K)
+    t = jnp.einsum("bd,kde->bke", X, A_inv, precision=HIGHEST)
+    quad = jnp.maximum(
+        jnp.einsum("bke,be->bk", t, X, precision=HIGHEST), 0.0)
     v = quad / staleness_inflation(cfg, hp, dt)[None, :]
     if jnp.ndim(lam) == 1:
         penalty = (hp.lambda_c + lam)[:, None] * c_tilde[None, :]   # (B, K)

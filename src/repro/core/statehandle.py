@@ -132,7 +132,8 @@ def decay_on_restore(cfg: RouterConfig, state: RouterState,
         lambda a, ai, bb: linucb.decay_statistics(
             cfg.statics, state.hyper, a, ai, bb, dt)
     )(state.A, state.A_inv, state.b)
-    theta = jnp.einsum("kij,kj->ki", A_inv, b)
+    theta = jnp.einsum("kij,kj->ki", A_inv, b,
+                       precision=linucb.HIGHEST)
     shift = jnp.asarray(elapsed, jnp.int32)
     tenants = state.tenants
     if tenants is not None:

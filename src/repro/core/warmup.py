@@ -12,6 +12,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
+from repro.core.linucb import HIGHEST
 from repro.core.types import ArmPrior, HyperParams, RouterConfig, RouterState
 
 Array = jax.Array
@@ -21,8 +22,9 @@ def fit_offline_prior(xs: Array, rs: Array, lambda0: float = 1.0) -> ArmPrior:
     """Ridge sufficient statistics from offline (context, reward) pairs for
     one arm: A_off = lambda0*I + X^T X, b_off = X^T r."""
     d = xs.shape[-1]
-    A = lambda0 * jnp.eye(d, dtype=jnp.float32) + xs.T @ xs
-    b = xs.T @ rs
+    A = lambda0 * jnp.eye(d, dtype=jnp.float32) + jnp.matmul(
+        xs.T, xs, precision=HIGHEST)
+    b = jnp.matmul(xs.T, rs, precision=HIGHEST)
     return ArmPrior(A_off=A.astype(jnp.float32), b_off=b.astype(jnp.float32))
 
 
@@ -63,7 +65,7 @@ def apply_warmup(
         A = A.at[k].set(A_k)
         A_inv = A_inv.at[k].set(Ainv_k)
         b = b.at[k].set(b_k)
-        theta = theta.at[k].set(Ainv_k @ b_k)
+        theta = theta.at[k].set(jnp.matmul(Ainv_k, b_k, precision=HIGHEST))
     import dataclasses
 
     return dataclasses.replace(state, A=A, A_inv=A_inv, b=b, theta=theta)

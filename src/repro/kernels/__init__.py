@@ -1,3 +1,25 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels of the routing and serving hot paths.
+
+Each kernel package holds ``kernel.py`` (the ``pallas_call``), ``ops.py``
+(the jitted padding wrapper callers use) and ``ref.py`` (a jnp oracle).
+"""
+from __future__ import annotations
+
+import jax
+
+
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run in the interpreter on this platform.
+
+    The one place the choice is made: on the CPU the kernels run in
+    interpret mode (the tests' path), on a TPU they are compiled, and
+    any other platform raises instead of silently interpreting.
+    """
+    platform = jax.default_backend()
+    if platform == "cpu":
+        return True
+    if platform == "tpu":
+        return False
+    raise RuntimeError(
+        f"Pallas kernels run compiled on 'tpu' or interpreted on 'cpu'; "
+        f"the default backend is {platform!r}")

@@ -6,6 +6,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.decode_attention.kernel import decode_attention_bkv
 
 
@@ -14,9 +15,11 @@ from repro.kernels.decode_attention.kernel import decode_attention_bkv
 )
 def decode_attention(
     q, k_cache, v_cache, valid, *, block_kv: int = 512,
-    interpret: bool = True, pad_head_dim: int = 128,
+    interpret: bool | None = None, pad_head_dim: int = 128,
 ):
     """q (B,1,H,hd), k/v (B,W,KV,hd), valid (W,) bool -> (B,1,H,hd)."""
+    if interpret is None:
+        interpret = interpret_mode()
     B, _, H, hd = q.shape
     W, KV = k_cache.shape[1], k_cache.shape[2]
     G = H // KV

@@ -10,6 +10,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.flash_attention.kernel import flash_attention_bh
 
 
@@ -30,7 +31,7 @@ def _pad_to(x, axis, mult):
 )
 def flash_attention(
     q, k, v, q_pos=None, k_pos=None, *, mode: str = "causal", window: int = 0,
-    block_q: int = 128, block_kv: int = 128, interpret: bool = True,
+    block_q: int = 128, block_kv: int = 128, interpret: bool | None = None,
     pad_head_dim: int = 128,
 ):
     """q (B,S,H,hd), k/v (B,T,KV,hd) -> (B,S,H,hd).
@@ -38,6 +39,8 @@ def flash_attention(
     Assumes contiguous positions starting at 0 (prefill); q_pos/k_pos args
     accepted for interface parity with the chunked XLA path.
     """
+    if interpret is None:
+        interpret = interpret_mode()
     B, S, H, hd = q.shape
     T, KV = k.shape[1], k.shape[2]
     G = H // KV

@@ -20,6 +20,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+# Mosaic runs an unannotated f32 dot below f32 precision (see
+# kernels/linucb_step/kernel.py); the scores are held to 1e-4 of jnp.
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _score_kernel(
     x_ref,      # (br, d)
@@ -36,13 +40,14 @@ def _score_kernel(
     alpha = alpha_ref[0, 0].astype(jnp.float32)
     exploit = jax.lax.dot_general(
         x, theta, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
+        preferred_element_type=jnp.float32, precision=HIGHEST,
     )                                                      # (br, K)
     cols = []
     for a in range(num_arms):                              # K static, small
         t = jax.lax.dot_general(
             x, ainv_ref[a].astype(jnp.float32),
             (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+            precision=HIGHEST,
         )                                                  # (br, d)
         q = jnp.maximum((t * x).sum(axis=1), 0.0)          # (br,)
         cols.append(q)

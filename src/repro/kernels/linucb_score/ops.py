@@ -8,6 +8,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.linucb_score.kernel import linucb_score_blocked
 
 
@@ -16,7 +17,7 @@ from repro.kernels.linucb_score.kernel import linucb_score_blocked
 )
 def linucb_score(
     x, theta, ainv, pen, infl, alpha, *, block_r: int = 256,
-    interpret: bool = True, pad_d: int = 32,
+    interpret: bool | None = None, pad_d: int = 32,
 ):
     """x (R,d), theta (K,d), ainv (K,d,d), pen (K,), infl (K,) -> (R,K).
 
@@ -25,6 +26,8 @@ def linucb_score(
     padded to a lane-friendly multiple (zero-padded contexts leave the
     quadratic form unchanged); R is padded to the row block.
     """
+    if interpret is None:
+        interpret = interpret_mode()
     R, d = x.shape
     K = theta.shape[0]
     pd = (-d) % pad_d

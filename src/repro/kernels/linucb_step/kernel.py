@@ -51,6 +51,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # Mirrors repro.core.linucb.GAMMA_FLOOR. Importing it would cycle
 # (core.__init__ -> router -> backend -> this package), and it must
@@ -66,6 +67,11 @@ NEG_INF = -1e30
 # hypf operand layout (1, 8): one lane-friendly f32 row of hyper scalars.
 HYP_ALPHA, HYP_GAMMA, HYP_ETA, HYP_AEMA, HYP_LBAR = range(5)
 
+# Mosaic runs an unannotated f32 dot below f32 precision. On a TPU v5e
+# that left theta 4.2e-3 from the jnp oracle after the 1,824-prompt
+# stream (the contract is 1e-4); Sherman-Morrison compounds the error.
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _step_kernel(
     # -- stats (aliased in/out: read once, written once) ----------------
@@ -73,7 +79,7 @@ def _step_kernel(
     ainv_ref,    # (K, d, d) cached inverses
     b_ref,       # (K, d)    reward accumulators
     theta_ref,   # (K, d)    ridge solutions
-    lu_ref,      # (1, K) i32 last statistics-update step
+    lu_ref,      # (1, K) i32 last statistics-update step (SMEM)
     # -- per-request block ----------------------------------------------
     x_ref,       # (Bp, d)  contexts
     rew_ref,     # (Bp, K)  environment reward matrix
@@ -88,7 +94,8 @@ def _step_kernel(
     int_ref,     # (1, 2) i32 [t_sel, force_arm]
     pacer_ref,   # (1, 4) f32 [lam, c_ema, budget, 0]
     # -- outputs ---------------------------------------------------------
-    oa_ref, oainv_ref, ob_ref, otheta_ref, olu_ref,
+    oa_ref, oainv_ref, ob_ref, otheta_ref,
+    olu_ref,     # (1, K) i32 (SMEM)
     oarm_ref,    # (Bp, 1) i32 chosen arm per request
     orc_ref,     # (Bp, 2) f32 realised (reward, cost) per request
     opacer_ref,  # (1, 4) f32 [lam', c_ema', budget, 0]
@@ -100,13 +107,14 @@ def _step_kernel(
     alpha = hyp_ref[0, HYP_ALPHA].astype(jnp.float32)
     exploit = jax.lax.dot_general(
         x, theta, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
+        preferred_element_type=jnp.float32, precision=HIGHEST,
     )                                                      # (Bp, K)
     cols = []
     for a in range(num_arms):                              # K static, small
         t = jax.lax.dot_general(
             x, ainv_ref[a].astype(jnp.float32),
             (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+            precision=HIGHEST,
         )                                                  # (Bp, d)
         q = jnp.maximum((t * x).sum(axis=1), 0.0)          # (Bp,)
         cols.append(q)
@@ -134,59 +142,69 @@ def _step_kernel(
     # VMEM. ``t_sel`` is the post-select clock (t + B): the oracle's
     # update_batch runs after select advanced t, and a same-arm second
     # update inside the block sees dt = 0 exactly as the sequential fold.
+    #
+    # Mosaic indexes refs, not values, by a traced scalar: row i's arm is
+    # read back from ``oarm_ref`` (a scalar load), its context and
+    # (reward, cost) are (1, .) row loads at a dynamic sublane offset, and
+    # the chosen arm's slabs are dynamic leading-axis indexes. The clock
+    # ``last_upd`` lives in SMEM, the only space that takes a scalar
+    # store. Everything arithmetic stays on (1, 1)/(1, d)/(d, d) vectors.
+    oa_ref[...] = a_ref[...]
+    oainv_ref[...] = ainv_ref[...]
+    ob_ref[...] = b_ref[...]
+    for a in range(num_arms):
+        olu_ref[0, a] = lu_ref[0, a]
     t_sel = int_ref[0, 0]
-    gamma = jnp.clip(hyp_ref[0, HYP_GAMMA].astype(jnp.float32),
-                     GAMMA_FLOOR, 1.0)
-    eta = hyp_ref[0, HYP_ETA].astype(jnp.float32)
-    a_ema = hyp_ref[0, HYP_AEMA].astype(jnp.float32)
-    lambda_bar = hyp_ref[0, HYP_LBAR].astype(jnp.float32)
-    budget = pacer_ref[0, 2].astype(jnp.float32)
+    gamma = jnp.clip(hyp_ref[:, HYP_GAMMA:HYP_GAMMA + 1].astype(jnp.float32),
+                     GAMMA_FLOOR, 1.0)                     # (1, 1)
+    eta = hyp_ref[:, HYP_ETA:HYP_ETA + 1].astype(jnp.float32)
+    a_ema = hyp_ref[:, HYP_AEMA:HYP_AEMA + 1].astype(jnp.float32)
+    lambda_bar = hyp_ref[:, HYP_LBAR:HYP_LBAR + 1].astype(jnp.float32)
+    budget = pacer_ref[:, 2:3].astype(jnp.float32)
 
     def body(i, pc):
-        lam, c_ema = pc
-        arm = arms[i]
-        xi = x_ref[i, :].astype(jnp.float32)               # (d,)
-        dtf = jnp.clip(t_sel - lu_ref[0, arm], 0, dt_max).astype(jnp.float32)
-        g = jnp.power(gamma, dtf)
-        A_a = a_ref[arm].astype(jnp.float32) * g + jnp.outer(xi, xi)
-        Ainv_a = ainv_ref[arm].astype(jnp.float32) / g
+        lam, c_ema = pc                                    # (1, 1) each
+        arm = oarm_ref[i, 0]
+        xi = x_ref[pl.ds(i, 1), :].astype(jnp.float32)     # (1, d)
+        rc = orc_ref[pl.ds(i, 1), :]                       # (1, 2)
+        dt = jnp.clip(t_sel - olu_ref[0, arm], 0, dt_max)
+        g = jnp.power(gamma, jnp.full((1, 1), dt, jnp.int32)
+                      .astype(jnp.float32))                # (1, 1)
+        A_a = oa_ref[arm] * g + xi.T * xi
+        Ainv_a = oainv_ref[arm] / g
         Ax = jax.lax.dot_general(
-            Ainv_a, xi, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                                  # (d,)
-        denom = 1.0 + (xi * Ax).sum()
-        Ainv_a = Ainv_a - jnp.outer(Ax, Ax) / denom
-        b_a = b_ref[arm].astype(jnp.float32) * g + r_all[i] * xi
-        a_ref[arm] = A_a
-        ainv_ref[arm] = Ainv_a
-        b_ref[arm] = b_a
-        lu_ref[0, arm] = t_sel
-        c_ema = (1.0 - a_ema) * c_ema + a_ema * c_all[i]   # Eq. 3
+            xi, Ainv_a, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=HIGHEST,
+        )                                                  # (A^-1 x)^T
+        denom = 1.0 + (xi * Ax).sum(axis=1, keepdims=True)
+        Ainv_a = Ainv_a - (Ax.T * Ax) / denom
+        b_a = ob_ref[pl.ds(arm, 1), :] * g + rc[:, 0:1] * xi
+        oa_ref[arm] = A_a
+        oainv_ref[arm] = Ainv_a
+        ob_ref[pl.ds(arm, 1), :] = b_a
+        olu_ref[0, arm] = t_sel
+        c_ema = (1.0 - a_ema) * c_ema + a_ema * rc[:, 1:2]  # Eq. 3
         lam = jnp.clip(lam + eta * (c_ema / budget - 1.0),  # Eq. 4
                        0.0, lambda_bar)
         return lam, c_ema
 
     lam, c_ema = jax.lax.fori_loop(
         0, num_valid, body,
-        (pacer_ref[0, 0].astype(jnp.float32),
-         pacer_ref[0, 1].astype(jnp.float32)))
-    opacer_ref[...] = jnp.stack(
-        [lam, c_ema, budget, jnp.float32(0.0)])[None, :]
+        (pacer_ref[:, 0:1].astype(jnp.float32),
+         pacer_ref[:, 1:2].astype(jnp.float32)))
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 4), 1)
+    opacer_ref[...] = jnp.where(
+        lane == 0, lam, jnp.where(lane == 1, c_ema,
+                                  jnp.where(lane == 2, budget, 0.0)))
 
     # Phase 4 — block-final theta refresh for every arm (K matvecs on the
     # already-updated VMEM statistics instead of num_valid per-request
     # ones; only the final theta is observable by the next score phase).
     for a in range(num_arms):
-        otheta_ref[a, :] = jax.lax.dot_general(
-            ainv_ref[a], b_ref[a], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    # Stats write-back (self-copy under aliasing: one HBM write total).
-    oa_ref[...] = a_ref[...]
-    oainv_ref[...] = ainv_ref[...]
-    ob_ref[...] = b_ref[...]
-    olu_ref[...] = lu_ref[...]
+        otheta_ref[a:a + 1, :] = jax.lax.dot_general(
+            ob_ref[a:a + 1, :], oainv_ref[a], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=HIGHEST,
+        )                                                  # (A^-1 b)^T
 
 
 def linucb_step_blocked(
@@ -235,9 +253,13 @@ def linucb_step_blocked(
         jax.ShapeDtypeStruct((Bp, 2), jnp.float32),     # (reward, cost)
         jax.ShapeDtypeStruct((1, 4), jnp.float32),      # pacer
     )
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
         kernel,
         out_shape=out_shape,
+        in_specs=[vmem] * 4 + [smem] + [vmem] * 11,
+        out_specs=[vmem] * 4 + [smem] + [vmem] * 3,
         input_output_aliases={0: 0, 1: 1, 2: 2, 3: 3, 4: 4},
         interpret=interpret,
     )(A, A_inv, b, theta, last_upd, x, rewards, costs, noise, forced,

@@ -14,6 +14,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.linucb_step.kernel import linucb_step_blocked
 
 
@@ -35,7 +36,7 @@ def linucb_step(
     forced,                # (B,) bool forced-override mask
     *,
     dt_max: int = 4096,
-    interpret: bool = True,
+    interpret: bool | None = None,
     pad_d: int = 32,
     pad_b: int = 8,
 ):
@@ -46,6 +47,8 @@ def linucb_step(
     operand (DESIGN.md §9): new values — including (alpha, gamma) stacks
     under the fabric's vmap axis — re-enter the same compiled kernel.
     """
+    if interpret is None:
+        interpret = interpret_mode()
     B, d = X.shape
     K = b.shape[0]
     pd = (-d) % pad_d

@@ -13,7 +13,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.linucb_step.kernel import (
-    GAMMA_FLOOR, HYP_AEMA, HYP_ALPHA, HYP_ETA, HYP_GAMMA, HYP_LBAR, NEG_INF,
+    GAMMA_FLOOR, HIGHEST, HYP_AEMA, HYP_ALPHA, HYP_ETA, HYP_GAMMA, HYP_LBAR,
+    NEG_INF,
 )
 
 
@@ -30,13 +31,14 @@ def linucb_step_ref(
     alpha = hypf[0, HYP_ALPHA].astype(jnp.float32)
     exploit = jax.lax.dot_general(
         x, theta, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
+        preferred_element_type=jnp.float32, precision=HIGHEST,
     )
     cols = []
     for a in range(K):
         t = jax.lax.dot_general(
             x, A_inv[a].astype(jnp.float32),
             (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+            precision=HIGHEST,
         )
         q = jnp.maximum((t * x).sum(axis=1), 0.0)
         cols.append(q)
@@ -56,33 +58,35 @@ def linucb_step_ref(
     rc = jnp.stack([r_all, c_all], axis=1)
 
     t_sel = ints[0, 0]
-    gamma = jnp.clip(hypf[0, HYP_GAMMA].astype(jnp.float32),
+    gamma = jnp.clip(hypf[:, HYP_GAMMA:HYP_GAMMA + 1].astype(jnp.float32),
                      GAMMA_FLOOR, 1.0)
-    eta = hypf[0, HYP_ETA].astype(jnp.float32)
-    a_ema = hypf[0, HYP_AEMA].astype(jnp.float32)
-    lambda_bar = hypf[0, HYP_LBAR].astype(jnp.float32)
-    budget = pacer[0, 2].astype(jnp.float32)
+    eta = hypf[:, HYP_ETA:HYP_ETA + 1].astype(jnp.float32)
+    a_ema = hypf[:, HYP_AEMA:HYP_AEMA + 1].astype(jnp.float32)
+    lambda_bar = hypf[:, HYP_LBAR:HYP_LBAR + 1].astype(jnp.float32)
+    budget = pacer[:, 2:3].astype(jnp.float32)
 
     def body(i, carry):
         A, A_inv, b, lu, lam, c_ema = carry
         arm = arms[i]
-        xi = x[i, :]
-        dtf = jnp.clip(t_sel - lu[0, arm], 0, dt_max).astype(jnp.float32)
-        g = jnp.power(gamma, dtf)
-        A_a = A[arm].astype(jnp.float32) * g + jnp.outer(xi, xi)
-        Ainv_a = A_inv[arm].astype(jnp.float32) / g
+        xi = jax.lax.dynamic_slice_in_dim(x, i, 1)
+        rc_i = jax.lax.dynamic_slice_in_dim(rc, i, 1)
+        dt = jnp.clip(t_sel - lu[0, arm], 0, dt_max)
+        g = jnp.power(gamma, jnp.full((1, 1), dt, jnp.int32)
+                      .astype(jnp.float32))
+        A_a = A[arm] * g + xi.T * xi
+        Ainv_a = A_inv[arm] / g
         Ax = jax.lax.dot_general(
-            Ainv_a, xi, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+            xi, Ainv_a, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=HIGHEST,
         )
-        denom = 1.0 + (xi * Ax).sum()
-        Ainv_a = Ainv_a - jnp.outer(Ax, Ax) / denom
-        b_a = b[arm].astype(jnp.float32) * g + r_all[i] * xi
+        denom = 1.0 + (xi * Ax).sum(axis=1, keepdims=True)
+        Ainv_a = Ainv_a - (Ax.T * Ax) / denom
+        b_a = jax.lax.dynamic_slice_in_dim(b, arm, 1) * g + rc_i[:, 0:1] * xi
         A = A.at[arm].set(A_a)
         A_inv = A_inv.at[arm].set(Ainv_a)
-        b = b.at[arm].set(b_a)
+        b = jax.lax.dynamic_update_slice_in_dim(b, b_a, arm, 0)
         lu = lu.at[0, arm].set(t_sel)
-        c_ema = (1.0 - a_ema) * c_ema + a_ema * c_all[i]
+        c_ema = (1.0 - a_ema) * c_ema + a_ema * rc_i[:, 1:2]
         lam = jnp.clip(lam + eta * (c_ema / budget - 1.0), 0.0, lambda_bar)
         return A, A_inv, b, lu, lam, c_ema
 
@@ -90,13 +94,16 @@ def linucb_step_ref(
         0, num_valid, body,
         (A.astype(jnp.float32), A_inv.astype(jnp.float32),
          b.astype(jnp.float32), last_upd,
-         pacer[0, 0].astype(jnp.float32), pacer[0, 1].astype(jnp.float32)))
-    pacer_out = jnp.stack([lam, c_ema, budget, jnp.float32(0.0)])[None, :]
+         pacer[:, 0:1].astype(jnp.float32), pacer[:, 1:2].astype(jnp.float32)))
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 4), 1)
+    pacer_out = jnp.where(
+        lane == 0, lam, jnp.where(lane == 1, c_ema,
+                                  jnp.where(lane == 2, budget, 0.0)))
 
-    theta_out = jnp.stack([
+    theta_out = jnp.concatenate([
         jax.lax.dot_general(
-            A_inv[a], b[a], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+            b[a:a + 1], A_inv[a], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=HIGHEST,
         )
         for a in range(K)
     ])

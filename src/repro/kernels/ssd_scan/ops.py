@@ -6,18 +6,22 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.ssd_scan.kernel import ssd_scan_bh
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan(
-    x, dt, A, B_in, C_in, D_skip, *, chunk: int = 128, interpret: bool = True
+    x, dt, A, B_in, C_in, D_skip, *, chunk: int = 128,
+    interpret: bool | None = None,
 ):
     """Same signature as models.ssm.ssd_chunked (h0=0).
 
     x (B,L,H,P), dt (B,L,H), A (H,), B_in/C_in (B,L,N), D_skip (H,)
     -> (y (B,L,H,P), h_final (B,H,N,P))
     """
+    if interpret is None:
+        interpret = interpret_mode()
     Bb, L, H, P = x.shape
     N = B_in.shape[-1]
     xf = x.transpose(0, 2, 1, 3).reshape(Bb * H, L, P)

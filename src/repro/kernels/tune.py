@@ -49,11 +49,10 @@ def autotune_block_r(
     candidates=BLOCK_R_CANDIDATES,
 ):
     """Time the score kernel at each row-tile candidate on synthetic
-    operands of shape ((R, d) x K arms). Returns (best_block_r,
+    operands of shape ((R, d) x K arms); ``interpret=None`` leaves the
+    choice to ``kernels.interpret_mode``. Returns (best_block_r,
     {block_r: seconds}). Candidates larger than R collapse to the same
     clamped tile; they are timed anyway so the table stays complete."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(R, d)), jnp.float32)
     theta = jnp.asarray(rng.normal(size=(K, d)), jnp.float32)

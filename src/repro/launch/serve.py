@@ -16,6 +16,65 @@ import argparse
 import sys
 
 
+DEFAULT_ARCHS = ("olmo-1b", "mamba2-370m", "deepseek-67b")
+TIERS = ("budget", "mid", "frontier")
+
+
+def build_server(arch_ids, budget: float, *, full_width=()):
+    """The routed portfolio: one ``ServedModel`` per architecture, at its
+    SMOKE width unless its id is in ``full_width`` (then its FULL config,
+    random weights from the arm's seed), always priced from the FULL
+    architecture's active parameters."""
+    from repro import configs
+    from repro.core.costs import price_from_active_params
+    from repro.core.features import fit_pca_whitener, hash_encode_batch
+    from repro.core.types import RouterConfig
+    from repro.data import make_request_stream
+    from repro.serving import PortfolioServer, ServedModel
+
+    corpus = [r["prompt"] for r in make_request_stream(400, seed=7)]
+    whitener = fit_pca_whitener(hash_encode_batch(corpus))
+    models = []
+    for i, a in enumerate(arch_ids):
+        cfg = (configs.get_config(a) if a in full_width
+               else configs.get_smoke(a))
+        pricing = price_from_active_params(
+            a, configs.get_config(a).active_params(), mean_req_tokens=600)
+        tier = TIERS[min(i, 2)]
+        models.append(ServedModel.init(cfg, pricing, tier, seed=i))
+        print(f"arm {i}: {cfg.name} @ ${pricing.price_per_1k:.2e}/1k tok "
+              f"({tier})")
+    return PortfolioServer(models, whitener, budget=budget,
+                           router_cfg=RouterConfig(max_arms=8),
+                           max_new_tokens=4)
+
+
+def serve_stream(server, stream, *, window: int, publish_every: int):
+    """Gateway loop: admission windows of ``window`` requests; feedback
+    is deferred to the learner plane and applied by a learn_tick every
+    ``publish_every`` windows (cadence 1 == the synchronous fold)."""
+    import numpy as np
+
+    def feed(backlog):
+        server.feedback_batch(
+            [r.request_id for r in backlog],
+            np.asarray([r.arm for r in backlog]),
+            np.asarray([r.reward for r in backlog]),
+            np.asarray([r.cost for r in backlog]))
+
+    results, backlog = [], []
+    for windows, i in enumerate(range(0, len(stream), window), start=1):
+        served = server.serve_batch(stream[i:i + window], defer_feedback=True)
+        results.extend(served)
+        backlog.extend(served)
+        if windows % publish_every == 0:
+            feed(backlog)
+            backlog = []
+    if backlog:
+        feed(backlog)
+    return results
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=60)
@@ -37,63 +96,24 @@ def main(argv=None):
     if args.dry_run:
         import subprocess
         rc = 0
-        for arch in args.arch or ["olmo-1b", "mamba2-370m", "deepseek-67b"]:
+        for arch in args.arch or DEFAULT_ARCHS:
             rc |= subprocess.call([
                 sys.executable, "-m", "repro.launch.dryrun",
                 "--arch", arch, "--shape", args.shape])
         raise SystemExit(rc)
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     import numpy as np
 
-    from repro import configs
-    from repro.core.costs import price_from_active_params
-    from repro.core.features import fit_pca_whitener, hash_encode_batch
-    from repro.core.types import RouterConfig
     from repro.data import make_request_stream
-    from repro.serving import PortfolioServer, ServedModel
 
-    arch_ids = args.arch or ["olmo-1b", "mamba2-370m", "deepseek-67b"]
-    tiers = ["budget", "mid", "frontier"]
-    corpus = [r["prompt"] for r in make_request_stream(400, seed=7)]
-    whitener = fit_pca_whitener(hash_encode_batch(corpus))
-    models = []
-    for i, a in enumerate(arch_ids):
-        smoke = configs.get_smoke(a)
-        # price the arm from the FULL architecture's active params
-        pricing = price_from_active_params(
-            a, configs.get_config(a).active_params(), mean_req_tokens=600)
-        models.append(ServedModel.init(smoke, pricing,
-                                       tiers[min(i, 2)], seed=i))
-        print(f"arm {i}: {a} @ ${pricing.price_per_1k:.2e}/1k tok "
-              f"({tiers[min(i, 2)]})")
-
-    server = PortfolioServer(models, whitener, budget=args.budget,
-                             router_cfg=RouterConfig(max_arms=8),
-                             max_new_tokens=4)
-    # Gateway loop: admission windows of --window requests; feedback is
-    # deferred to the learner plane and applied by a learn_tick every
-    # --publish-every windows (cadence 1 == the synchronous fold).
-    stream = list(make_request_stream(args.requests, seed=11))
-    results, backlog, windows = [], [], 0
-    for i in range(0, len(stream), args.window):
-        window = stream[i:i + args.window]
-        served = server.serve_batch(window, defer_feedback=True)
-        results.extend(served)
-        backlog.extend(served)
-        windows += 1
-        if windows % args.publish_every == 0:
-            server.feedback_batch(
-                [r.request_id for r in backlog],
-                np.asarray([r.arm for r in backlog]),
-                np.asarray([r.reward for r in backlog]),
-                np.asarray([r.cost for r in backlog]))
-            backlog = []
-    if backlog:
-        server.feedback_batch(
-            [r.request_id for r in backlog],
-            np.asarray([r.arm for r in backlog]),
-            np.asarray([r.reward for r in backlog]),
-            np.asarray([r.cost for r in backlog]))
+    server = build_server(args.arch or DEFAULT_ARCHS, args.budget)
+    models = [m for m in server.models if m is not None]
+    results = serve_stream(
+        server, list(make_request_stream(args.requests, seed=11)),
+        window=args.window, publish_every=args.publish_every)
     reward = np.mean([r.reward for r in results])
     cost = np.mean([r.cost for r in results])
     traffic = {m.name: 0 for m in models}

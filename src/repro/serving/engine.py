@@ -64,15 +64,19 @@ class ServedModel:
 
     PROMPT_BUCKET = 32  # pad prompts to a fixed bucket: one compile
 
+    def prompt_window(self, tokens: np.ndarray) -> np.ndarray:
+        """The (S,) token window ``generate`` prefills: the prompt
+        left-padded with BOS (so the causal suffix is the real prompt) to
+        a PROMPT_BUCKET multiple, at most four buckets long."""
+        pad = (-len(tokens)) % self.PROMPT_BUCKET or (
+            self.PROMPT_BUCKET if len(tokens) == 0 else 0)
+        return np.concatenate([np.ones(pad, np.int32), tokens])[
+            -4 * self.PROMPT_BUCKET:]
+
     def generate(self, tokens: np.ndarray, max_new: int = 16,
                  key: Optional[jax.Array] = None,
                  temperature: float = 0.0) -> np.ndarray:
-        pad = (-len(tokens)) % self.PROMPT_BUCKET or (
-            self.PROMPT_BUCKET if len(tokens) == 0 else 0)
-        # left-pad with BOS so the causal suffix is the real prompt
-        toks = np.concatenate([np.ones(pad, np.int32), tokens])[
-            -4 * self.PROMPT_BUCKET:]
-        toks = jnp.asarray(toks[None, :])
+        toks = jnp.asarray(self.prompt_window(tokens)[None, :])
         cache_len = toks.shape[1] + max_new
         logits, caches = self._prefill(toks, cache_len)
         out = []

@@ -1,5 +1,6 @@
 """Per-kernel validation: shape/dtype sweeps against pure-jnp oracles,
-executed with interpret=True on CPU (TPU is the lowering target)."""
+run in interpret mode on the CPU (``kernels.interpret_mode``); the same
+kernels compile for a described TPU in tests/test_tpu_compile.py."""
 import functools
 
 import jax
@@ -17,6 +18,7 @@ from repro.kernels.linucb_score.ops import linucb_score
 from repro.kernels.linucb_score.ref import linucb_score_ref
 from repro.kernels.linucb_score.kernel import linucb_score_blocked
 from repro.kernels.linucb_step.kernel import linucb_step_blocked
+from repro.kernels.linucb_step.ops import linucb_step
 from repro.kernels.linucb_step.ref import linucb_step_ref
 
 RNG = np.random.default_rng(42)
@@ -401,3 +403,36 @@ class TestLinUCBStepFused:
         jax.block_until_ready(s2.A)
         assert state.A.is_deleted()
         assert s2.A.shape == (3, 8, 8)
+
+
+# ---------------------------------------------------------------------------
+# Interpret-mode decision (kernels.interpret_mode): one place, no fallback
+# ---------------------------------------------------------------------------
+
+
+class TestInterpretMode:
+    def test_cpu_interprets(self):
+        from repro.kernels import interpret_mode
+        assert jax.default_backend() == "cpu"
+        assert interpret_mode() is True
+
+    def test_tpu_compiles(self, monkeypatch):
+        from repro.kernels import interpret_mode
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert interpret_mode() is False
+
+    @pytest.mark.parametrize("platform", ["gpu", "rocm"])
+    def test_other_platform_raises(self, monkeypatch, platform):
+        from repro.kernels import interpret_mode
+        monkeypatch.setattr(jax, "default_backend", lambda: platform)
+        with pytest.raises(RuntimeError, match=platform):
+            interpret_mode()
+
+    @pytest.mark.parametrize("wrapper", [
+        linucb_score, linucb_step, decode_attention, flash_attention,
+        ssd_scan])
+    def test_no_wrapper_defaults_to_interpret(self, wrapper):
+        """Every kernel wrapper leaves the choice to the platform."""
+        import inspect
+        param = inspect.signature(wrapper).parameters["interpret"]
+        assert param.default is None
