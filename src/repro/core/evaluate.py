@@ -254,8 +254,9 @@ def make_states(
         keys, budgets, hp, ne_in, tab)
 
 
-def _pad_env_arrays(cfg: RouterConfig, env: Environment):
-    """Pad (T, K) matrices out to max_arms with harmless fillers."""
+def _pad_env_arrays(cfg: RouterConfig, env: Environment, put=jnp.asarray):
+    """Pad (T, K) matrices out to max_arms with harmless fillers; ``put``
+    sends each to the device."""
     pad = cfg.max_arms - env.k
     rewards = np.concatenate(
         [env.rewards, np.zeros((env.n, pad), np.float32)], axis=1
@@ -263,7 +264,7 @@ def _pad_env_arrays(cfg: RouterConfig, env: Environment):
     costs = np.concatenate(
         [env.costs, np.full((env.n, pad), 1e9, np.float32)], axis=1
     )
-    return jnp.asarray(env.contexts), jnp.asarray(rewards), jnp.asarray(costs)
+    return put(env.contexts), put(rewards), put(costs)
 
 
 def build_run_streams(
@@ -271,28 +272,31 @@ def build_run_streams(
     env: Environment | Sequence[Environment],
     seeds: Sequence[int],
     shuffle: bool = True,
+    put=jnp.asarray,
 ):
     """Padded per-seed stream tensors for ``run`` and the sweep fabric.
 
     Returns ``(xs, rmat, cmat, stream_axes, env0)`` where ``stream_axes``
     is 0 for per-seed stacked streams (a sequence of environments, or one
     environment with per-seed shuffles) and None for one shared stream.
+    Every host array goes to the device through ``put`` (the sweep
+    fabric passes one that counts the bytes).
     """
     if isinstance(env, (list, tuple)):
         assert len(env) == len(seeds), (len(env), len(seeds))
-        padded = [_pad_env_arrays(cfg, e) for e in env]
+        padded = [_pad_env_arrays(cfg, e, put) for e in env]
         xs = jnp.stack([p[0] for p in padded])
         rmat = jnp.stack([p[1] for p in padded])
         cmat = jnp.stack([p[2] for p in padded])
         return xs, rmat, cmat, 0, env[0]
-    xs, rmat, cmat = _pad_env_arrays(cfg, env)
+    xs, rmat, cmat = _pad_env_arrays(cfg, env, put)
     if shuffle:
         perms = np.stack([
             np.random.default_rng(int(s)).permutation(env.n) for s in seeds
         ])
-        xs = xs[jnp.asarray(perms)]
-        rmat = rmat[jnp.asarray(perms)]
-        cmat = cmat[jnp.asarray(perms)]
+        xs = xs[put(perms)]
+        rmat = rmat[put(perms)]
+        cmat = cmat[put(perms)]
         return xs, rmat, cmat, 0, env
     return xs, rmat, cmat, None, env
 

@@ -193,16 +193,38 @@ def _expand_tenants(tables, C: int, S: int):
         f"{jnp.shape(tables.budget)}")
 
 
-def _tile_conditions(arr: Array, C: int, sh) -> Array:
+def _tile_conditions(arr: Array, C: int) -> np.ndarray:
     """Stack per-seed stream tensors along a leading condition axis,
-    (S, ...) -> (C*S, ...), placed directly under the grid sharding:
-    the tile happens in host memory and ``device_put`` transfers each
-    device only its shard, so no single device ever holds the C-times
-    tensor (device 0 would OOM first on large accelerator grids)."""
+    (S, ...) -> (C*S, ...), in host memory: ``_shard_grid`` then places
+    the tile directly under the grid sharding, so ``device_put``
+    transfers each device only its shard and no single device ever
+    holds the C-times tensor (device 0 would OOM first on large
+    accelerator grids)."""
     a = np.asarray(arr)
-    tiled = np.broadcast_to(a[None], (C,) + a.shape).reshape(
+    return np.broadcast_to(a[None], (C,) + a.shape).reshape(
         (C * a.shape[0],) + a.shape[1:])
-    return jax.device_put(tiled, sh)
+
+
+def _d2h_nbytes(x) -> int:
+    """Bytes ``np.asarray(x)`` reads back from the devices: all of a
+    device array, nothing of a host one."""
+    return int(x.nbytes) if isinstance(x, jax.Array) else 0
+
+
+def _h2d_nbytes(x, sharding=None) -> int:
+    """Bytes ``jax.device_put(x, sharding)`` sends from host memory,
+    from shapes alone: every device's shard of a host array (a
+    replicated array once per device; all of it to the default device
+    where ``sharding`` is None), in the dtype it lands in. An array
+    already on a device crosses no host link."""
+    if isinstance(x, jax.Array):
+        return 0
+    itemsize = np.dtype(jax.dtypes.canonicalize_dtype(
+        np.result_type(x))).itemsize
+    if sharding is None:
+        return int(np.prod(np.shape(x))) * itemsize
+    shard = sharding.shard_shape(np.shape(x))
+    return len(sharding.device_set) * int(np.prod(shard)) * itemsize
 
 
 def _shard_grid(states: RouterState, streams, stream_axes, C, devices,
@@ -210,28 +232,44 @@ def _shard_grid(states: RouterState, streams, stream_axes, C, devices,
     """Place the flattened grid on a 1-D device mesh: state leaves,
     condition-tiled streams, per-element scenario-param leaves and any
     ``extras`` (per-element timeline operands) split along the grid
-    axis, shared streams replicated."""
-    n = int(states.t.shape[0])
-    mesh = mesh_lib.make_grid_mesh(n, devices)
-    sh = mesh_lib.grid_sharding(mesh)
-    rep = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
-    states = jax.device_put(states, sh)
-    # The state stack is donated to the fabric call; donation requires
-    # one buffer per leaf, but identical constant-initialised leaves
-    # (zeroed last_upd/last_play, A == A_inv at lambda0 = 1) can share
-    # one. Copy to uniquify — a few MB next to the grid compute.
-    states = jax.tree.map(lambda l: jnp.array(l, copy=True), states)
-    if stream_axes == 0:
-        # Pre-stacked per-element streams pass through; per-seed (S,...)
-        # streams are condition-tiled.
-        streams = tuple(
-            jax.device_put(a, sh) if a.shape[0] == n
-            else _tile_conditions(a, C, sh) for a in streams)
-    else:
-        streams = tuple(jax.device_put(a, rep) for a in streams)
-    if params is not None:
-        params = jax.tree.map(lambda l: jax.device_put(l, sh), params)
-    extras = tuple(jax.device_put(a, sh) for a in extras)
+    axis, shared streams replicated. Recorded as the ``sweep.place``
+    span, with the bytes it moves over the host link as ``h2d_bytes``
+    and ``d2h_bytes``."""
+    with jax.profiler.TraceAnnotation("sweep.place") as span:
+        n = int(states.t.shape[0])
+        mesh = mesh_lib.make_grid_mesh(n, devices)
+        sh = mesh_lib.grid_sharding(mesh)
+        rep = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+        h2d = d2h = 0
+
+        def put(a, sharding):
+            nonlocal h2d
+            h2d += sum(_h2d_nbytes(l, sharding) for l in jax.tree.leaves(a))
+            return jax.device_put(a, sharding)
+
+        states = put(states, sh)
+        # The state stack is donated to the fabric call; donation
+        # requires one buffer per leaf, but identical constant-initialised
+        # leaves (zeroed last_upd/last_play, A == A_inv at lambda0 = 1)
+        # can share one. Copy to uniquify — a few MB next to the grid
+        # compute.
+        states = jax.tree.map(lambda l: jnp.array(l, copy=True), states)
+        if stream_axes == 0:
+            # Pre-stacked per-element streams pass through; per-seed
+            # (S,...) streams are condition-tiled.
+            placed = []
+            for a in streams:
+                if a.shape[0] != n:
+                    d2h += _d2h_nbytes(a)
+                    a = _tile_conditions(a, C)
+                placed.append(put(a, sh))
+            streams = tuple(placed)
+        else:
+            streams = tuple(put(a, rep) for a in streams)
+        if params is not None:
+            params = put(params, sh)
+        extras = tuple(put(a, sh) for a in extras)
+        span.set_metadata(h2d_bytes=h2d, d2h_bytes=d2h)
     return states, streams, params, extras
 
 
@@ -312,23 +350,38 @@ def _chunk_wrap(vm, n_chunks: int, scan_in):
     return chunked
 
 
+def _grid_program(name: str, body, in_axes, n_chunks: int, scan_in):
+    """Jit one fabric program: ``body`` (one grid element's whole
+    stream) vmapped over the flat grid axis and chunked, the state stack
+    donated. The jitted function is called ``name``, so its XLA module
+    reads ``jit_<name>``, and each element's scan, whose body is the
+    grid step, runs under ``jax.named_scope("grid_step")``: a trace
+    finds the program and its step operations by name."""
+
+    def one(state, *operands):
+        TRACE_COUNT[0] += 1       # moves only while tracing
+        with jax.named_scope("grid_step"):
+            return body(state, *operands)
+
+    step = _chunk_wrap(jax.vmap(one, in_axes=in_axes), n_chunks, scan_in)
+
+    def program(states, *operands):
+        return step(states, *operands)
+
+    program.__name__ = program.__qualname__ = name
+    return jax.jit(program, donate_argnums=0)
+
+
 @functools.lru_cache(maxsize=64)
 def _cached_grid_fn(statics, stream_axes, batch_size, n_chunks=1):
     """One jitted fabric program per (Statics, stream layout, data
     plane, chunking); budgets, seeds, priors and hyper-parameters are
     data, so every grid with the same shapes re-enters the same
     executable. The state stack is donated."""
-    body = evaluate.stream_body(statics, batch_size)
-
-    def one(state, x, rm, cm):
-        TRACE_COUNT[0] += 1       # moves only while tracing
-        return body(state, x, rm, cm)
-
-    vm = jax.vmap(one, in_axes=(0, stream_axes, stream_axes, stream_axes))
-    return jax.jit(
-        _chunk_wrap(vm, n_chunks, (stream_axes == 0,) * 3),
-        donate_argnums=0,
-    )
+    return _grid_program(
+        "grid_program", evaluate.stream_body(statics, batch_size),
+        (0, stream_axes, stream_axes, stream_axes), n_chunks,
+        (stream_axes == 0,) * 3)
 
 
 @functools.lru_cache(maxsize=64)
@@ -338,17 +391,11 @@ def _cached_grid_fn_tenants(statics, stream_axes, batch_size, n_chunks=1):
     flattened (C*S, L) layout, sharded with the states). Tables and ids
     are data — a new (tenants x budgets) grid with the same shapes
     re-enters this executable with zero retraces."""
-    body = evaluate.stream_body_tenants(statics, batch_size)
-
-    def one(state, x, rm, cm, tids):
-        TRACE_COUNT[0] += 1       # moves only while tracing
-        return body(state, x, rm, cm, tids)
-
-    vm = jax.vmap(one, in_axes=(0, stream_axes, stream_axes, stream_axes, 0))
-    return jax.jit(
-        _chunk_wrap(vm, n_chunks, (stream_axes == 0,) * 3 + (True,)),
-        donate_argnums=0,
-    )
+    return _grid_program(
+        "grid_program_tenants",
+        evaluate.stream_body_tenants(statics, batch_size),
+        (0, stream_axes, stream_axes, stream_axes, 0), n_chunks,
+        (stream_axes == 0,) * 3 + (True,))
 
 
 # ---------------------------------------------------------------------------
@@ -491,71 +538,97 @@ def run_grid(
     grid fuses into this one compiled sharded call. Requires
     ``batch_size`` (tenant routing is a batched-data-plane feature).
     """
-    budgets, seeds = _check_grid_args(budgets, seeds, condition_edits)
-    if (tenant_tables is None) != (tenant_ids is None):
-        raise ValueError("pass tenant_tables and tenant_ids together")
-    if tenant_tables is not None and not batch_size:
-        raise ValueError(
-            "tenant grids need batch_size: tenant routing is a batched-"
-            "data-plane feature (DESIGN.md §15)")
-    if condition_edits is not None and any(
-            getattr(e, "param_overrides", None) for e in condition_edits):
-        raise ValueError(
-            "param_edit pins scenario payload leaves; use it with "
-            "run_scenario_grid (run_grid evaluates plain streams with "
-            "no scenario events)")
-    budgets, seeds, flat_b, flat_s = _flatten_grid(budgets, seeds)
-    C, S = len(budgets), len(seeds)
-    # Deliberate host->device staging: stream tensors and the stacked
-    # state grid are built eagerly once per call. Annotating it keeps
-    # jax.transfer_guard("disallow") usable around the compiled
-    # dispatch below, where an implicit transfer would be a real bug.
-    with jax.transfer_guard("allow"):
-        xs, rmat, cmat, stream_axes, env0 = evaluate.build_run_streams(
-            cfg, env, seeds, shuffle)
-        states = evaluate.make_states(
-            cfg, env0, flat_b, flat_s,
-            priors=priors, n_eff=_per_condition_axis(n_eff, C, S),
-            pacer_enabled=pacer_enabled,
-            hyper=_expand_hyper(hyper, C, S),
-            tenants=_expand_tenants(tenant_tables, C, S),
-        )
-        if condition_edits is not None:
-            states = _apply_condition_edits(states, condition_edits, S)
-        extras = ()
-        if tenant_ids is not None:
-            tids = np.asarray(tenant_ids, np.int32)
-            if tids.ndim == 1:
-                tids = np.broadcast_to(tids, (C * S,) + tids.shape)
-            elif tids.ndim == 2 and tids.shape[0] == S and S != C * S:
-                tids = np.broadcast_to(
-                    tids[None], (C,) + tids.shape).reshape(C * S, -1)
-            elif not (tids.ndim == 2 and tids.shape[0] == C * S):
-                raise ValueError(
-                    f"tenant_ids must be (L,) shared, ({S}, L) per-seed "
-                    f"or ({C * S}, L) per-element; got shape {tids.shape}")
-            extras = (jnp.asarray(np.ascontiguousarray(tids)),)
-        states, streams, _, extras = _shard_grid(
-            states, (xs, rmat, cmat), stream_axes, C, devices,
-            extras=extras)
+    with jax.profiler.TraceAnnotation("sweep.run_grid"):
+        budgets, seeds = _check_grid_args(budgets, seeds, condition_edits)
+        if (tenant_tables is None) != (tenant_ids is None):
+            raise ValueError("pass tenant_tables and tenant_ids together")
+        if tenant_tables is not None and not batch_size:
+            raise ValueError(
+                "tenant grids need batch_size: tenant routing is a batched-"
+                "data-plane feature (DESIGN.md §15)")
+        if condition_edits is not None and any(
+                getattr(e, "param_overrides", None) for e in condition_edits):
+            raise ValueError(
+                "param_edit pins scenario payload leaves; use it with "
+                "run_scenario_grid (run_grid evaluates plain streams with "
+                "no scenario events)")
+        budgets, seeds, flat_b, flat_s = _flatten_grid(budgets, seeds)
+        C, S = len(budgets), len(seeds)
+        # Deliberate host->device staging: stream tensors and the stacked
+        # state grid are built eagerly once per call. Annotating it keeps
+        # jax.transfer_guard("disallow") usable around the compiled
+        # dispatch below, where an implicit transfer would be a real bug.
+        with jax.transfer_guard("allow"):
+            with jax.profiler.TraceAnnotation("sweep.streams") as span:
+                sent = 0
 
-    if tenant_ids is not None:
-        fn = _cached_grid_fn_tenants(cfg.statics, stream_axes, batch_size,
-                                     _n_chunks(C * S, chunk_size))
-    else:
-        fn = _cached_grid_fn(cfg.statics, stream_axes, batch_size,
-                             _n_chunks(C * S, chunk_size))
-    finals, (arms, r, c, lam) = fn(states, *streams, *extras)
-    res = GridResult(
-        budgets=budgets, seeds=seeds,
-        arms=np.asarray(arms).reshape(C, S, -1),
-        rewards=np.asarray(r).reshape(C, S, -1),
-        costs=np.asarray(c).reshape(C, S, -1),
-        lams=np.asarray(lam).reshape(C, S, -1),
-    )
+                def put(a):
+                    nonlocal sent
+                    sent += _h2d_nbytes(a)
+                    return jnp.asarray(a)
+
+                xs, rmat, cmat, stream_axes, env0 = \
+                    evaluate.build_run_streams(cfg, env, seeds, shuffle, put)
+                span.set_metadata(h2d_bytes=sent)
+            with jax.profiler.TraceAnnotation("sweep.states"):
+                states = evaluate.make_states(
+                    cfg, env0, flat_b, flat_s,
+                    priors=priors, n_eff=_per_condition_axis(n_eff, C, S),
+                    pacer_enabled=pacer_enabled,
+                    hyper=_expand_hyper(hyper, C, S),
+                    tenants=_expand_tenants(tenant_tables, C, S),
+                )
+                if condition_edits is not None:
+                    states = _apply_condition_edits(states, condition_edits,
+                                                    S)
+                extras = ()
+                if tenant_ids is not None:
+                    tids = np.asarray(tenant_ids, np.int32)
+                    if tids.ndim == 1:
+                        tids = np.broadcast_to(tids, (C * S,) + tids.shape)
+                    elif tids.ndim == 2 and tids.shape[0] == S and S != C * S:
+                        tids = np.broadcast_to(
+                            tids[None], (C,) + tids.shape).reshape(C * S, -1)
+                    elif not (tids.ndim == 2 and tids.shape[0] == C * S):
+                        raise ValueError(
+                            f"tenant_ids must be (L,) shared, ({S}, L) "
+                            f"per-seed or ({C * S}, L) per-element; got "
+                            f"shape {tids.shape}")
+                    # Sent by _shard_grid, straight to each device's shard.
+                    extras = (np.ascontiguousarray(tids),)
+            states, streams, _, extras = _shard_grid(
+                states, (xs, rmat, cmat), stream_axes, C, devices,
+                extras=extras)
+
+        if tenant_ids is not None:
+            fn = _cached_grid_fn_tenants(cfg.statics, stream_axes, batch_size,
+                                         _n_chunks(C * S, chunk_size))
+        else:
+            fn = _cached_grid_fn(cfg.statics, stream_axes, batch_size,
+                                 _n_chunks(C * S, chunk_size))
+        finals, (arms, r, c, lam) = _launch_and_read(
+            fn, (states, *streams, *extras), C, S)
+        res = GridResult(budgets=budgets, seeds=seeds, arms=arms, rewards=r,
+                         costs=c, lams=lam)
     if return_states:
         return res, finals
     return res
+
+
+def _launch_and_read(fn, operands, C: int, S: int):
+    """Run a compiled grid program and read its per-step traces back as
+    (C, S, T) host arrays: ``sweep.launch`` (the dispatch),
+    ``sweep.wait`` (the device running it) and ``sweep.readback`` (the
+    copies, with their ``d2h_bytes``). Returns (final states, (arms,
+    rewards, costs, lams))."""
+    with jax.profiler.TraceAnnotation("sweep.launch"):
+        finals, outs = fn(*operands)
+    with jax.profiler.TraceAnnotation("sweep.wait"):
+        jax.block_until_ready(outs)
+    with jax.profiler.TraceAnnotation(
+            "sweep.readback", d2h_bytes=sum(_d2h_nbytes(o) for o in outs)):
+        outs = tuple(np.asarray(o).reshape(C, S, -1) for o in outs)
+    return finals, outs
 
 
 # ---------------------------------------------------------------------------
@@ -607,15 +680,15 @@ def _expand_params(params, C: int, S: int):
     """Stack param leaves onto the flattened condition-major (C*S,)
     axis: (C,)-leading leaves repeat each entry S times (like budgets),
     already-flat (C*S,)-leading leaves pass through, everything else
-    broadcasts to all grid elements."""
+    broadcasts to all grid elements. The stacks stay in host memory
+    until ``_shard_grid`` sends each device its shard."""
     def ex(leaf):
-        a = np.asarray(leaf)
+        a = np.asarray(leaf, np.float32)
         if a.ndim and a.shape[0] == C * S:
-            return jnp.asarray(a, jnp.float32)
+            return a
         if a.ndim and a.shape[0] == C and C != C * S:
-            return jnp.asarray(np.repeat(a, S, axis=0), jnp.float32)
-        return jnp.asarray(np.broadcast_to(a, (C * S,) + a.shape),
-                           jnp.float32)
+            return np.repeat(a, S, axis=0)
+        return np.ascontiguousarray(np.broadcast_to(a, (C * S,) + a.shape))
 
     return jax.tree.map(ex, params)
 
@@ -635,15 +708,10 @@ def _cached_scenario_grid_fn(
            scenario_lib._env_sig(env), batch_size, n_chunks)
 
     def make():
-        body = scenario_lib.spec_body(cfg, spec, env, batch_size)
-
-        def one(state, x, rm, cm, params):
-            TRACE_COUNT[0] += 1       # moves only while tracing
-            return body(state, x, rm, cm, params)
-
-        vm = jax.vmap(one, in_axes=(0, 0, 0, 0, 0))
-        return jax.jit(_chunk_wrap(vm, n_chunks, (True,) * 4),
-                       donate_argnums=0)
+        return _grid_program(
+            "scenario_grid_program",
+            scenario_lib.spec_body(cfg, spec, env, batch_size), 0,
+            n_chunks, (True,) * 4)
 
     return scenario_lib.lru_get(_SCEN_CACHE, key, make, _SCEN_CACHE_MAX)
 
@@ -663,15 +731,10 @@ def _cached_timeline_grid_fn(
            scenario_lib._env_sig(env), batch_size, n_chunks)
 
     def make():
-        body = scenario_lib.timeline_body(cfg, spec, env, batch_size)
-
-        def one(state, x, rm, cm, params, ev_ts, horizon):
-            TRACE_COUNT[0] += 1       # moves only while tracing
-            return body(state, x, rm, cm, params, ev_ts, horizon)
-
-        vm = jax.vmap(one, in_axes=(0,) * 7)
-        return jax.jit(_chunk_wrap(vm, n_chunks, (True,) * 6),
-                       donate_argnums=0)
+        return _grid_program(
+            "timeline_grid_program",
+            scenario_lib.timeline_body(cfg, spec, env, batch_size), 0,
+            n_chunks, (True,) * 6)
 
     return scenario_lib.lru_get(_SCEN_CACHE, key, make, _SCEN_CACHE_MAX)
 
@@ -801,7 +864,7 @@ def run_scenario_grid(
             states, (xs, rmat, cmat), 0, C, devices, pstack)
         fn = _cached_scenario_grid_fn(cfg, spec, env, batch_size,
                                       _n_chunks(C * S, chunk_size))
-        finals, (arms, r, c, lam) = fn(states, *streams, pstack)
+        operands = (states, *streams, pstack)
         bounds = spec.bounds
     else:
         tls, per_cond = _normalize_timelines(timelines, C, S)
@@ -812,26 +875,21 @@ def run_scenario_grid(
             states, host_streams, 0, C, devices, pstack, extras=(ev, hz))
         fn = _cached_timeline_grid_fn(cfg, spec, env, batch_size,
                                       _n_chunks(C * S, chunk_size))
-        finals, (arms, r, c, lam) = fn(states, *streams, pstack, ev, hz)
+        operands = (states, *streams, pstack, ev, hz)
         bounds = None
         if per_cond:
             cond_bounds = tuple(r_.bounds for r_ in rspecs)
             horizons = tuple(r_.horizon for r_ in rspecs)
+    finals, (arms, r, c, lam) = _launch_and_read(fn, operands, C, S)
     cond_params = {
         n: np.asarray(params.get(n))
         for n in params.names
         if np.ndim(params.get(n)) and np.shape(params.get(n))[0] == C
     } or None
     res = GridResult(
-        budgets=budgets, seeds=seeds,
-        arms=np.asarray(arms).reshape(C, S, -1),
-        rewards=np.asarray(r).reshape(C, S, -1),
-        costs=np.asarray(c).reshape(C, S, -1),
-        lams=np.asarray(lam).reshape(C, S, -1),
-        bounds=bounds,
-        params=cond_params,
-        cond_bounds=cond_bounds,
-        horizons=horizons,
+        budgets=budgets, seeds=seeds, arms=arms, rewards=r, costs=c,
+        lams=lam, bounds=bounds, params=cond_params,
+        cond_bounds=cond_bounds, horizons=horizons,
     )
     if return_states:
         return res, finals
