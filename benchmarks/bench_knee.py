@@ -14,7 +14,8 @@ the AUC grid (and once more for the differently-shaped Phase-2 grid)
 instead of compiling one program per (alpha, gamma) cell. The cells
 enter as per-condition ``HyperParams`` leaves and each cell's
 gamma-derived warm start (n_eff via Eq. 13) as a per-condition
-``n_eff`` vector, both applied inside ``make_states``' single vmap.
+``n_eff`` vector, both applied inside ``make_states``' one compiled
+program.
 
 ``--baseline`` additionally runs the pre-fusion protocol — one fabric
 call per cell for the budget frontier plus one ``evaluate.run`` per cell
@@ -92,9 +93,9 @@ def score_grid_fused(t_adapt, use_priors, seeds, *, env=None, priors=None,
 
     The (alpha, gamma) cells ride the condition axis as per-condition
     ``HyperParams`` leaves, and each cell's gamma-derived warm start as a
-    per-condition ``n_eff`` — both applied inside ``make_states``' single
-    vmap (DESIGN.md §7/§9), so the host-side setup cost does not grow
-    with the number of cells.
+    per-condition ``n_eff`` — both applied inside ``make_states``' one
+    compiled program (DESIGN.md §7/§9), so the host-side setup cost does
+    not grow with the number of cells.
 
     ``chunk_size`` bounds the live per-step working set of each fabric
     call (sweep.run_grid's scan-over-chunks; results bit-identical):

@@ -23,6 +23,11 @@ from repro.core.types import (
 
 Array = jax.Array
 
+# Incremented inside the traced state builder: moves only when XLA
+# (re)traces it, so tests can assert that a new grid of the same shape
+# re-enters the compiled program (tests/trace_guard.assert_traces).
+TRACE_COUNT = [0]
+
 
 @dataclasses.dataclass(frozen=True)
 class RunResult:
@@ -114,37 +119,38 @@ class RunResult:
 def pad_priors(cfg: RouterConfig, priors: Sequence[ArmPrior | None]):
     """Pad a per-arm prior list out to ``max_arms`` slots (the layout
     ``warmup.apply_warmup`` expects); shared with sweep.warmup_edit so
-    per-condition warm starts match ``make_states`` exactly."""
+    per-condition warm starts load the slots ``make_states`` loads."""
     pad = cfg.max_arms - len(priors)
     assert pad >= 0, (len(priors), cfg.max_arms)
     return list(priors) + [None] * pad
 
 
 def _hyper_stack(cfg: RouterConfig, hyper: Optional[HyperParams], n: int):
-    """(leaves, vmap in_axes) for a hyper spec that is either one shared
-    ``HyperParams`` or one with (n,)-stacked leaves (a per-state axis)."""
+    """A hyper spec — one shared ``HyperParams`` or one with (n,)-stacked
+    leaves (a per-state axis) — as host float32 (n,) stacks. Every stack
+    then reaches the state builder in one layout, so a shared value and
+    the same value repeated per state build bit-identical states."""
     hp = cfg.hyper if hyper is None else hyper
     if isinstance(hp, HyperParams):
         hp.validate()
-    leaves, axes = {}, {}
+    leaves = {}
     for name in HYPER_FIELDS:
-        leaf = jnp.asarray(getattr(hp, name), jnp.float32)
+        leaf = np.asarray(getattr(hp, name), np.float32)
         if leaf.ndim not in (0, 1) or (leaf.ndim == 1
                                        and leaf.shape[0] != n):
             raise ValueError(
                 f"hyper.{name} must be a scalar or a ({n},) stack; got "
                 f"shape {leaf.shape}")
-        leaves[name] = leaf
-        axes[name] = 0 if leaf.ndim else None
-    return HyperParams(**leaves), HyperParams(**axes)
+        leaves[name] = np.broadcast_to(leaf, (n,))
+    return HyperParams(**leaves)
 
 
-def _tenant_stack(tenants: "tenancy.TenantTable", n: int):
-    """(table, vmap in_axes) for a tenant table that is either one shared
-    (T,) table — broadcast to every stacked state — or one with (n, T)
-    leaves (a per-state axis, the sweep fabric's flattened grid). Budgets
-    are positivity-checked here (host boundary, satellite of the Eq. 4
-    division hazard) when concrete."""
+def _tenant_axis(tenants: "tenancy.TenantTable", n: int):
+    """The vmap axis of a tenant table that is either one shared (T,)
+    table — broadcast to every stacked state (None) — or one with
+    (n, T) leaves (0: a per-state axis, the sweep fabric's flattened
+    grid). Budgets are positivity-checked here (host boundary, satellite
+    of the Eq. 4 division hazard) when concrete."""
     ndim = jnp.ndim(tenants.budget)
     if not isinstance(tenants.budget, jax.core.Tracer):
         b = np.asarray(tenants.budget)
@@ -153,16 +159,47 @@ def _tenant_stack(tenants: "tenancy.TenantTable", n: int):
                 "tenant budgets must be > 0 ($/request ceilings); got "
                 f"min={b.min()!r}")
     if ndim == 1:
-        axes = tenancy.TenantTable(lam=None, c_ema=None, budget=None,
-                                   enabled=None, pulls=None, spend=None)
-        return tenants, axes
+        return None
     if ndim == 2 and tenants.budget.shape[0] == n:
-        axes = tenancy.TenantTable(lam=0, c_ema=0, budget=0,
-                                   enabled=0, pulls=0, spend=0)
-        return tenants, axes
+        return 0
     raise ValueError(
         f"tenants.budget must be (T,) shared or ({n}, T) per-state; got "
         f"shape {jnp.shape(tenants.budget)}")
+
+
+@functools.lru_cache(maxsize=64)
+def _cached_states_fn(statics, prior_slots, pacer_enabled, tenant_axis):
+    """One jitted state builder per stack structure: ``statics``,
+    ``prior_slots`` (None for a cold stack, else one bool per arm slot
+    saying whether it holds a prior), ``pacer_enabled`` and the vmap
+    axis of a tenant table (None shared, 0 per state); jit adds the
+    number of states from the operands' shapes. Seeds, budgets, prices,
+    the active mask, the priors' statistics and the per-state n_eff,
+    hyper leaves and tables are operands, so a new grid of the same
+    shape re-enters the same executable."""
+
+    def one(seed, b, preq, p1k, active, h, ne, prior_stats, tb):
+        st = init_state(
+            statics, preq, p1k, b,
+            key=jax.random.PRNGKey(seed), active=active,
+            pacer_enabled=pacer_enabled, hyper=h, tenants=tb,
+        )
+        if prior_slots is not None:
+            it = iter(prior_stats)
+            padded = [ArmPrior(*next(it)) if has else None
+                      for has in prior_slots]
+            st = warmup.apply_warmup(statics, st, padded, ne)
+        return st
+
+    # Named for its caller, so its XLA module reads ``jit_make_states``.
+    def make_states(seeds, budgets, preq, p1k, active, hp, ne, prior_stats,
+                    tab):
+        TRACE_COUNT[0] += 1       # moves only while tracing
+        return jax.vmap(
+            one, in_axes=(0, 0, None, None, None, 0, 0, None, tenant_axis),
+        )(seeds, budgets, preq, p1k, active, hp, ne, prior_stats, tab)
+
+    return jax.jit(make_states)
 
 
 def make_states(
@@ -178,9 +215,19 @@ def make_states(
     hyper: Optional[HyperParams] = None,
     tenants: Optional["tenancy.TenantTable"] = None,
 ) -> RouterState:
-    """Stacked initial states, one per seed: a single ``jax.vmap`` over
-    (PRNG key, budget, hyper, n_eff) tuples — everything else broadcasts
-    — not a Python loop + ``jnp.stack``.
+    """Stacked initial states, one per seed, built by ONE cached compiled
+    program (``_cached_states_fn``): ``init_state``, the §3.4 warm start
+    and the PRNG keys, vmapped over (seed, budget, hyper, n_eff, tenant
+    table) inside one ``jax.jit`` — everything else broadcasts. The
+    program is keyed on the stack's structure alone (statics, warm or
+    cold and which arm slots hold a prior, pacer on or off, a shared or
+    per-state tenant table, and through jit the number of states and
+    tenants); every value is an operand,
+    so fresh seeds and budgets of the same shape re-enter it with zero
+    retraces (``TRACE_COUNT``). Budgets, hyper leaves and n_eff reach it
+    as per-state stacks whether given shared or stacked, so one value
+    shared and the same value repeated per state build bit-identical
+    states through one program.
 
     ``budget`` is either one ceiling shared by every state or a sequence
     aligned with ``seeds``: the ceiling lives in ``PacerState.budget``, a
@@ -192,7 +239,7 @@ def make_states(
     state (α, γ, ...) axis for fused hyper grids. ``n_eff`` likewise: a
     scalar, or one pseudo-count per stacked state (the knee grid derives
     n_eff from each cell's gamma via Eq. 13), applied inside the same
-    vmap — all warm or all cold; a mixed stack would need the warmup
+    program — all warm or all cold; a mixed stack would need the warmup
     branch to be data-dependent (use per-condition ``condition_edits``
     for that instead).
 
@@ -202,6 +249,7 @@ def make_states(
     """
     k = env.k
     assert k <= cfg.max_arms, (k, cfg.max_arms)
+    n = len(seeds)
     b_host = np.asarray(budget, np.float32)
     if not np.all(b_host > 0.0):
         raise ValueError(
@@ -212,7 +260,7 @@ def make_states(
     n_active = k if active_arms is None else active_arms
     active = np.zeros(cfg.max_arms, bool)
     active[:n_active] = True
-    hp, hp_axes = _hyper_stack(cfg, hyper, len(seeds))
+    hp = _hyper_stack(cfg, hyper, n)
     ne = np.asarray(n_eff, np.float32)
     warm = priors is not None and bool(np.any(ne > 0))
     if warm and ne.ndim and not np.all(ne > 0):
@@ -220,38 +268,25 @@ def make_states(
             "mixed warm/cold n_eff in one stack: apply_warmup at n_eff=0 "
             "is not a no-op, so warm-vs-cold cannot share the vmapped "
             "branch — stack it via condition_edits instead")
-    if ne.ndim and ne.shape != (len(seeds),):
+    if ne.ndim and ne.shape != (n,):
         raise ValueError(
             f"n_eff must be a scalar or one value per state; got shape "
-            f"{ne.shape} for {len(seeds)} states")
-    padded = pad_priors(cfg, list(priors)) if warm else None
-
-    def one(key, b, h, ne_):
-        st = init_state(
-            cfg, preq, p1k, b,
-            key=key, active=jnp.asarray(active),
-            pacer_enabled=pacer_enabled, hyper=h,
-        )
-        if warm:
-            st = warmup.apply_warmup(cfg, st, padded, ne_)
-        return st
-
-    keys = jax.vmap(jax.random.PRNGKey)(
-        jnp.asarray([int(s) for s in seeds], jnp.uint32))
-    budgets = jnp.broadcast_to(
-        jnp.asarray(budget, jnp.float32), (len(seeds),))
-    ne_in = jnp.asarray(ne) if ne.ndim else float(ne)
-    ne_ax = 0 if ne.ndim else None
-    if tenants is None:
-        return jax.vmap(one, in_axes=(0, 0, hp_axes, ne_ax))(
-            keys, budgets, hp, ne_in)
-    tab, tab_axes = _tenant_stack(tenants, len(seeds))
-
-    def one_t(key, b, h, ne_, tb):
-        return dataclasses.replace(one(key, b, h, ne_), tenants=tb)
-
-    return jax.vmap(one_t, in_axes=(0, 0, hp_axes, ne_ax, tab_axes))(
-        keys, budgets, hp, ne_in, tab)
+            f"{ne.shape} for {n} states")
+    prior_slots, prior_stats = None, ()
+    if warm:
+        padded = pad_priors(cfg, list(priors))
+        prior_slots = tuple(p is not None for p in padded)
+        prior_stats = tuple((p.A_off, p.b_off) for p in padded
+                            if p is not None)
+    tab_axis = None if tenants is None else _tenant_axis(tenants, n)
+    build = _cached_states_fn(cfg.statics, prior_slots,
+                              bool(pacer_enabled), tab_axis)
+    seeds_host = np.asarray([int(s) for s in seeds], np.uint32)
+    # Deliberate host->device staging of a few KB of operands.
+    with jax.transfer_guard("allow"):
+        return build(seeds_host, np.broadcast_to(b_host, (n,)), preq, p1k,
+                     active, hp, np.broadcast_to(ne, (n,)), prior_stats,
+                     tenants)
 
 
 def _pad_env_arrays(cfg: RouterConfig, env: Environment, put=jnp.asarray):

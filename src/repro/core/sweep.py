@@ -424,8 +424,11 @@ def hyper_edit(hyper: Optional[HyperParams] = None, **overrides):
 def warmup_edit(cfg: RouterConfig, priors, n_eff: float):
     """A condition edit applying the §3.4 warm start — per-condition
     ``n_eff`` (e.g. derived from gamma via Eq. 13) stacked on the grid
-    axis. Identical math to ``make_states(priors=..., n_eff=...)``, so
-    fused cells stay bit-identical to their looped counterparts."""
+    axis. The same math as ``make_states(priors=..., n_eff=...)``, run
+    eagerly: where ``make_states``' compiled program contracts a
+    multiply-add into one rounding, ``b`` and ``theta`` can differ from
+    its states in the last bit, too little to move a decision in the
+    grids the tests pin (tests/test_hyperparams.py)."""
     padded = evaluate.pad_priors(cfg, list(priors))
 
     def edit(st: RouterState) -> RouterState:
@@ -515,10 +518,11 @@ def run_grid(
 
     ``hyper`` leaves and ``n_eff`` may be per-condition (C,) vectors
     (DESIGN.md §9): they are repeated S times onto the flattened stack
-    and applied inside ``make_states``' single vmap — the cheap way to
-    put an (α, γ, n_eff) grid on the condition axis (``condition_edits``
-    pays one eager vmapped edit per condition instead, which dominates
-    wall clock on wide grids).
+    and applied inside ``make_states``' one cached compiled program
+    (keyed on the stack's structure, every value an operand) — the
+    cheap way to put an (α, γ, n_eff) grid on the condition axis
+    (``condition_edits`` pays one eager vmapped edit per condition
+    instead, which dominates wall clock on wide grids).
 
     ``devices`` defaults to ``jax.devices()``; the flattened C*S axis is
     sharded over the largest device count dividing it.

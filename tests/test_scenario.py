@@ -573,3 +573,65 @@ class TestMakeStatesVectorized:
         for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-6, atol=1e-7)
+
+    @pytest.mark.parametrize("case", ["cold", "warm", "per_state_vectors",
+                                      "active_arms", "tenant_table"])
+    def test_compiled_builder_matches_eager_vmap(self, env, case):
+        """The one compiled builder equals the construction it replaced:
+        ``init_state`` and the warm start vmapped eagerly, one dispatch
+        per primitive."""
+        import jax.numpy as jnp
+        from repro.core import tenancy, warmup
+        from repro.core.types import HYPER_FIELDS, HyperParams, init_state
+        priors = evaluate.fit_warmup_priors(CFG, env)
+        seeds = (7, 2 ** 31 + 5, 4294967295)
+        budget = np.asarray([3e-4, 6.6e-4, 1.9e-3], np.float32)
+        kw = {"priors": priors, "n_eff": 1164.0}
+        if case == "cold":
+            kw = {}
+        elif case == "per_state_vectors":
+            kw = {"priors": priors,
+                  "n_eff": np.asarray([100.0, 1164.0, 5000.0], np.float32),
+                  "hyper": HyperParams(
+                      alpha=np.asarray([0.01, 0.1, 0.5], np.float32),
+                      gamma=np.asarray([0.99, 0.997, 1.0], np.float32),
+                      lambda0=np.asarray([0.5, 1.0, 2.0], np.float32))}
+        elif case == "active_arms":
+            kw["active_arms"] = 2
+        elif case == "tenant_table":
+            kw["tenants"] = tenancy.stack_tables(
+                [tenancy.make_table([1e-4 * (i + 1), 5e-4]) for i in range(3)])
+        got = evaluate.make_states(CFG, env, budget, seeds, **kw)
+
+        pad = CFG.max_arms - env.k
+        preq = np.concatenate([env.prices_per_req,
+                               np.full(pad, 1e9)]).astype(np.float32)
+        p1k = np.concatenate([env.prices_per_1k,
+                              np.full(pad, 1e9)]).astype(np.float32)
+        active = np.zeros(CFG.max_arms, bool)
+        active[:kw.get("active_arms", env.k)] = True
+        hp = kw.get("hyper", CFG.hyper)
+        hp = HyperParams(**{n: jnp.asarray(getattr(hp, n), jnp.float32)
+                            for n in HYPER_FIELDS})
+        hp_axes = HyperParams(**{n: 0 if jnp.ndim(getattr(hp, n)) else None
+                                 for n in HYPER_FIELDS})
+        ne = jnp.asarray(kw.get("n_eff", 0.0), jnp.float32)
+        tab = kw.get("tenants")
+
+        def one(key, b, h, ne_, tb):
+            st = init_state(CFG, preq, p1k, b, key=key,
+                            active=jnp.asarray(active), hyper=h, tenants=tb)
+            if "priors" in kw:
+                st = warmup.apply_warmup(CFG, st,
+                                         list(priors) + [None] * pad, ne_)
+            return st
+
+        keys = jax.vmap(jax.random.PRNGKey)(jnp.asarray(seeds, jnp.uint32))
+        want = jax.vmap(one, in_axes=(0, 0, hp_axes, 0 if ne.ndim else None,
+                                      None if tab is None else 0))(
+            keys, jnp.asarray(budget), hp, ne, tab)
+        assert (jax.tree.structure(got) == jax.tree.structure(want))
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            assert a.shape == b.shape and a.dtype == b.dtype
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-6, atol=1e-7)

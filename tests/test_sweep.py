@@ -109,6 +109,38 @@ class TestOneCompiledProgram:
             sweep.run_grid(CFG, env, [2 * b for b in BUDGET_SWEEP],
                            seeds=seeds, priors=priors, n_eff=1164.0)
 
+    def test_fresh_grid_reenters_the_state_builder(self, env, priors):
+        """Fresh seeds and budgets of the same shape re-enter the one
+        compiled state builder; another C*S traces it once."""
+        seeds = tuple(range(100, 113))       # C*S = 39, met nowhere else
+        sweep.run_grid(CFG, env, BUDGETS, seeds=seeds, priors=priors,
+                       n_eff=1164.0)
+        with assert_traces(evaluate, 0, what="state builder retraced"):
+            sweep.run_grid(CFG, env, [3 * b for b in BUDGETS],
+                           seeds=tuple(range(200, 213)), priors=priors,
+                           n_eff=1164.0)
+        with assert_traces(evaluate, 1, what="a new C*S traces once"):
+            sweep.run_grid(CFG, env, BUDGETS[:2], seeds=seeds,
+                           priors=priors, n_eff=1164.0)
+
+    def test_shared_and_per_state_hyper_share_one_program(self, env,
+                                                           priors):
+        """A hyper value or n_eff given once and the same value given per
+        state reach the builder in one layout: one program, the same
+        bits."""
+        hp = HyperParams(alpha=0.05, gamma=0.999)
+        n = len(SEEDS)
+        shared = evaluate.make_states(CFG, env, 6.6e-4, SEEDS,
+                                      priors=priors, n_eff=1164.0, hyper=hp)
+        with assert_traces(evaluate, 0, what="per-state layout retraced"):
+            stacked = evaluate.make_states(
+                CFG, env, 6.6e-4, SEEDS, priors=priors,
+                n_eff=np.full(n, 1164.0, np.float32),
+                hyper=HyperParams(alpha=np.full(n, 0.05, np.float32),
+                                  gamma=np.full(n, 0.999, np.float32)))
+        for a, b in zip(jax.tree.leaves(shared), jax.tree.leaves(stacked)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
     def test_grid_result_accessors(self, env):
         grid = sweep.run_grid(CFG, env, BUDGETS, seeds=SEEDS)
         assert len(grid) == 3

@@ -9,7 +9,7 @@ import jax
 import numpy as np
 import pytest
 
-from perfbench import program_spans
+from perfbench import program_spans, trace
 from repro.core import evaluate, simulator, sweep, tenancy
 from repro.core.scenario import PriceChange, ScenarioSpec, Timeline
 from repro.core.types import RouterConfig
@@ -27,6 +27,13 @@ SPEC = ScenarioSpec(horizon=48, events=(PriceChange(24, 1, 0.1),),
 def env():
     return simulator.make_benchmark(
         seed=0, splits={"train": 128, "val": 16, "test": 64}).test
+
+
+@pytest.fixture(scope="module")
+def priors():
+    train = simulator.make_benchmark(
+        seed=0, splits={"train": 128, "val": 16, "test": 64}).train
+    return evaluate.fit_warmup_priors(CFG, train)
 
 
 @pytest.fixture(scope="module")
@@ -88,6 +95,21 @@ def test_phases_cover_the_call(shuffle, env, envs, tmp_path):
     assert call.name == "sweep.run_grid"
     covered = sum(p.end - p.start for p in phases)
     assert covered >= 0.9 * (call.end - call.start)
+
+
+def test_states_phase_is_one_dispatch(envs, priors, tmp_path):
+    """On a warm call the whole ``sweep.states`` phase, warm start
+    included, is one execution of one compiled program."""
+    def call():
+        sweep.run_grid(CFG, envs, BUDGETS, seeds=SEEDS, shuffle=False,
+                       priors=priors, n_eff=1164.0)
+
+    call()
+    with profiled(tmp_path):
+        call()
+    progs = trace.programs_of(trace.Tracer(str(tmp_path)).load(),
+                              "sweep.states")
+    assert list(progs.values()) == [1]
 
 
 @pytest.mark.parametrize("layout", ["per_seed_envs", "one_env_shuffled"])
