@@ -893,52 +893,55 @@ def build_streams(
     configs, budgets and payload values, and the host-side gather +
     device put is the expensive part.
     """
-    assert env.k <= cfg.max_arms, (env.k, cfg.max_arms)
-    assert pad_to is None or pad_to >= spec.horizon, (pad_to, spec.horizon)
-    _validate_state_events(spec, env.k)
     mix_values = _host_mix_values(spec, params)
     cache_key = (spec_key(spec), cfg.max_arms, pad_to,
                  tuple(int(s) for s in seeds), _env_content_sig(env),
                  tuple((nm, v.tobytes()) for nm, v in mix_values.items()))
 
     def make():
-        mods = _segment_mods(spec)
-        envs, cache = [], {}
-        for m in mods:
-            if m not in cache:
-                cache[m] = _transformed_env(env, m)
-            envs.append(cache[m])
-        pad = cfg.max_arms - env.k
-        xs, rs, cs = [], [], []
-        for s in seeds:
-            idxs = compile_indices(spec, env, int(s), mix_values)
-            x = np.concatenate(
-                [envs[j].contexts[i] for j, i in enumerate(idxs)])
-            r = np.concatenate(
-                [envs[j].rewards[i] for j, i in enumerate(idxs)])
-            c = np.concatenate(
-                [envs[j].costs[i] for j, i in enumerate(idxs)])
-            if pad:
-                r = np.concatenate(
-                    [r, np.zeros((len(r), pad), np.float32)], 1)
-                c = np.concatenate(
-                    [c, np.full((len(c), pad), 1e9, np.float32)], 1)
-            extra = 0 if pad_to is None else pad_to - len(x)
-            if extra:
-                x = np.concatenate(
-                    [x, np.zeros((extra,) + x.shape[1:], x.dtype)])
-                r = np.concatenate(
-                    [r, np.zeros((extra, r.shape[1]), np.float32)])
-                c = np.concatenate(
-                    [c, np.full((extra, c.shape[1]), 1e9, np.float32)])
-            xs.append(x), rs.append(r), cs.append(c)
-        return (
-            jnp.asarray(np.stack(xs)),
-            jnp.asarray(np.stack(rs), jnp.float32),
-            jnp.asarray(np.stack(cs), jnp.float32),
-        )
+        xs, rs, cs = _host_streams(cfg, spec, env, seeds, mix_values, pad_to)
+        return jnp.asarray(xs), jnp.asarray(rs), jnp.asarray(cs)
 
     return lru_get(_STREAM_CACHE, cache_key, make, _STREAM_CACHE_MAX)
+
+
+def _host_streams(cfg, spec, env, seeds, mix_values, pad_to):
+    """``build_streams``' stacks as host arrays, uncached."""
+    assert env.k <= cfg.max_arms, (env.k, cfg.max_arms)
+    assert pad_to is None or pad_to >= spec.horizon, (pad_to, spec.horizon)
+    _validate_state_events(spec, env.k)
+    mods = _segment_mods(spec)
+    envs, cache = [], {}
+    for m in mods:
+        if m not in cache:
+            cache[m] = _transformed_env(env, m)
+        envs.append(cache[m])
+    pad = cfg.max_arms - env.k
+    xs, rs, cs = [], [], []
+    for s in seeds:
+        idxs = compile_indices(spec, env, int(s), mix_values)
+        x = np.concatenate(
+            [envs[j].contexts[i] for j, i in enumerate(idxs)])
+        r = np.concatenate(
+            [envs[j].rewards[i] for j, i in enumerate(idxs)])
+        c = np.concatenate(
+            [envs[j].costs[i] for j, i in enumerate(idxs)])
+        if pad:
+            r = np.concatenate(
+                [r, np.zeros((len(r), pad), np.float32)], 1)
+            c = np.concatenate(
+                [c, np.full((len(c), pad), 1e9, np.float32)], 1)
+        extra = 0 if pad_to is None else pad_to - len(x)
+        if extra:
+            x = np.concatenate(
+                [x, np.zeros((extra,) + x.shape[1:], x.dtype)])
+            r = np.concatenate(
+                [r, np.zeros((extra, r.shape[1]), np.float32)])
+            c = np.concatenate(
+                [c, np.full((extra, c.shape[1]), 1e9, np.float32)])
+        xs.append(x), rs.append(r), cs.append(c)
+    return (np.stack(xs), np.stack(rs).astype(np.float32, copy=False),
+            np.stack(cs).astype(np.float32, copy=False))
 
 
 # ---------------------------------------------------------------------------
@@ -996,95 +999,83 @@ def build_timeline_streams(
         block replaces the per-segment concatenate.
 
     This was the N >> 1e4 scenario-Monte-Carlo bottleneck flagged in
-    DESIGN.md §12. Ineligible specs (see ``timeline_streams_
+    DESIGN.md §12. The stacks are host arrays, so the fabric sends each
+    device only its shard, and they are not cached: a Monte Carlo draws
+    fresh seeds and event times on every call, and a cache of
+    (N_flat, T, ...) stacks would hold one stack per call it never
+    reads again. Ineligible specs (see ``timeline_streams_
     vectorizable``) take the per-timeline loop below — same contract,
-    same cache.
+    host arrays, uncached.
     """
     N = len(rspecs)
     assert N == len(seed_groups) and N > 0, (N, len(seed_groups))
     T = pad_to if pad_to is not None else spec.horizon
-    cache_key = (
-        "timeline-stack", spec_key(spec), cfg.max_arms, pad_to,
-        tuple((r_.horizon, tuple(e.t for e in r_.events)) for r_ in rspecs),
-        tuple(tuple(int(s) for s in g) for g in seed_groups),
-        _env_content_sig(env),
-        tuple((nm, v.tobytes())
-              for nm, v in _host_mix_values(spec, params).items()),
-    )
-
-    def make_fallback():
-        parts = [build_streams(cfg, r_, env, tuple(g), params=params,
-                               pad_to=pad_to)
-                 for r_, g in zip(rspecs, seed_groups)]
-        return tuple(
-            jnp.concatenate([p[j] for p in parts]) for j in range(3))
 
     if not timeline_streams_vectorizable(spec):
-        return lru_get(_STREAM_CACHE, cache_key, make_fallback,
-                       _STREAM_CACHE_MAX)
+        mix_values = _host_mix_values(spec, params)
+        parts = [_host_streams(cfg, r_, env, g, mix_values, pad_to)
+                 for r_, g in zip(rspecs, seed_groups)]
+        return tuple(
+            np.concatenate([p[j] for p in parts]) for j in range(3))
 
-    def make():
-        k, n, d = env.k, env.n, env.contexts.shape[1]
-        assert k <= cfg.max_arms, (k, cfg.max_arms)
-        pad = cfg.max_arms - k
-        ctx = np.ascontiguousarray(env.contexts)
-        heff = np.asarray([r_.horizon for r_ in rspecs], np.int64)
-        assert int(heff.max()) <= T, (int(heff.max()), T)
+    k, n, d = env.k, env.n, env.contexts.shape[1]
+    assert k <= cfg.max_arms, (k, cfg.max_arms)
+    pad = cfg.max_arms - k
+    ctx = np.ascontiguousarray(env.contexts)
+    heff = np.asarray([r_.horizon for r_ in rspecs], np.int64)
+    assert int(heff.max()) <= T, (int(heff.max()), T)
 
-        # One full-horizon index draw per seed, shared by every timeline.
-        uniq = sorted({int(s) for g in seed_groups for s in g})
-        idx_full = {
-            s: np.random.default_rng(spec.stream_seed_base + s)
-            .integers(0, n, size=T)
-            for s in uniq
-        }
+    # One full-horizon index draw per seed, shared by every timeline.
+    uniq = sorted({int(s) for g in seed_groups for s in g})
+    idx_full = {
+        s: np.random.default_rng(spec.stream_seed_base + s)
+        .integers(0, n, size=T)
+        for s in uniq
+    }
 
-        # One transformed env per distinct segment-settings value.
-        variants: Dict[_SegmentMods, int] = {}
-        rew_list, cost_list = [], []
-        vt = np.zeros((N, T), np.int64)   # variant in force at each step
-        for i, r_ in enumerate(rspecs):
-            _validate_state_events(r_, k)
-            vids = []
-            for m in _segment_mods(r_):
-                if m not in variants:
-                    variants[m] = len(variants)
-                    e = _transformed_env(env, m)
-                    rew_list.append(np.asarray(e.rewards, np.float32))
-                    cost_list.append(np.asarray(e.costs, np.float32))
-                vids.append(variants[m])
-            lens = [b - a for a, b in r_.segments]
-            vt[i, :heff[i]] = np.repeat(vids, lens)
-        REW = np.stack(rew_list)          # (V, n, k)
-        COST = np.stack(cost_list)
-        if pad:
-            REW = np.concatenate(
-                [REW, np.zeros((len(REW), n, pad), np.float32)], 2)
-            COST = np.concatenate(
-                [COST, np.full((len(COST), n, pad), 1e9, np.float32)], 2)
+    # One transformed env per distinct segment-settings value.
+    variants: Dict[_SegmentMods, int] = {}
+    rew_list, cost_list = [], []
+    vt = np.zeros((N, T), np.int64)   # variant in force at each step
+    for i, r_ in enumerate(rspecs):
+        _validate_state_events(r_, k)
+        vids = []
+        for m in _segment_mods(r_):
+            if m not in variants:
+                variants[m] = len(variants)
+                e = _transformed_env(env, m)
+                rew_list.append(np.asarray(e.rewards, np.float32))
+                cost_list.append(np.asarray(e.costs, np.float32))
+            vids.append(variants[m])
+        lens = [b - a for a, b in r_.segments]
+        vt[i, :heff[i]] = np.repeat(vids, lens)
+    REW = np.stack(rew_list)          # (V, n, k)
+    COST = np.stack(cost_list)
+    if pad:
+        REW = np.concatenate(
+            [REW, np.zeros((len(REW), n, pad), np.float32)], 2)
+        COST = np.concatenate(
+            [COST, np.full((len(COST), n, pad), 1e9, np.float32)], 2)
 
-        total = sum(len(g) for g in seed_groups)
-        xs = np.zeros((total, T, d), ctx.dtype)
-        rs = np.zeros((total, T, cfg.max_arms), np.float32)
-        cs = np.full((total, T, cfg.max_arms), 1e9, np.float32)
-        row = 0
-        for i in range(N):
-            S = len(seed_groups[i])
-            if not S:
-                continue
-            idx = np.stack([idx_full[int(s)] for s in seed_groups[i]])
-            h = int(heff[i])
-            # one gather per block; steps >= h stay at the padding
-            # values (zero contexts/rewards, 1e9 costs)
-            xs[row:row + S, :h] = ctx[idx[:, :h]]
-            v = vt[i, None, :h]
-            rs[row:row + S, :h] = REW[v, idx[:, :h]]
-            cs[row:row + S, :h] = COST[v, idx[:, :h]]
-            row += S
-        return (jnp.asarray(xs), jnp.asarray(rs, jnp.float32),
-                jnp.asarray(cs, jnp.float32))
-
-    return lru_get(_STREAM_CACHE, cache_key, make, _STREAM_CACHE_MAX)
+    total = sum(len(g) for g in seed_groups)
+    xs = np.zeros((total, T, d), ctx.dtype)
+    rs = np.zeros((total, T, cfg.max_arms), np.float32)
+    cs = np.full((total, T, cfg.max_arms), 1e9, np.float32)
+    row = 0
+    for i in range(N):
+        S = len(seed_groups[i])
+        if not S:
+            continue
+        idx = np.stack([idx_full[int(s)] for s in seed_groups[i]])
+        h = int(heff[i])
+        # one gather per block; steps >= h stay at the padding
+        # values (zero contexts/rewards, 1e9 costs)
+        xs[row:row + S, :h] = ctx[idx[:, :h]]
+        v = vt[i, None, :h]
+        rs[row:row + S, :h] = REW[v, idx[:, :h]]
+        cs[row:row + S, :h] = COST[v, idx[:, :h]]
+        row += S
+    return xs, rs, cs
 
 
 # ---------------------------------------------------------------------------

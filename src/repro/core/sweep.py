@@ -843,58 +843,63 @@ def run_scenario_grid(
     padding; composes with ``condition_edits``/``scenario_params``/
     ``chunk_size`` and both data planes unchanged.
     """
-    budgets, seeds = _check_grid_args(budgets, seeds, condition_edits)
-    budgets, seeds, flat_b, flat_s = _flatten_grid(budgets, seeds)
-    C, S = len(budgets), len(seeds)
-    params = _merged_scenario_params(
-        scenario_params if scenario_params is not None
-        else scenario_lib.ScenarioParams(), condition_edits, C, S)
-    params = scenario_lib.resolve_params(spec, params)
-    full = params.updated(**scenario_lib.auto_param_values(spec))
-    states = evaluate.make_states(
-        cfg, env, flat_b, flat_s,
-        priors=priors, n_eff=_per_condition_axis(n_eff, C, S),
-        pacer_enabled=pacer_enabled,
-        active_arms=spec.init_active, hyper=_expand_hyper(hyper, C, S),
-    )
-    if condition_edits is not None:
-        states = _apply_condition_edits(states, condition_edits, S)
-    pstack = _expand_params(full, C, S)
-    cond_bounds = horizons = None
-    if timelines is None:
-        xs, rmat, cmat = scenario_lib.build_streams(cfg, spec, env, seeds,
-                                                    params=params)
-        states, streams, pstack, _ = _shard_grid(
-            states, (xs, rmat, cmat), 0, C, devices, pstack)
-        fn = _cached_scenario_grid_fn(cfg, spec, env, batch_size,
-                                      _n_chunks(C * S, chunk_size))
-        operands = (states, *streams, pstack)
-        bounds = spec.bounds
-    else:
-        tls, per_cond = _normalize_timelines(timelines, C, S)
-        rspecs, host_streams, ev, hz = _timeline_grid_operands(
-            cfg, spec, env, tls, per_cond, seeds, flat_s, params,
-            batch_size)
-        states, streams, pstack, (ev, hz) = _shard_grid(
-            states, host_streams, 0, C, devices, pstack, extras=(ev, hz))
-        fn = _cached_timeline_grid_fn(cfg, spec, env, batch_size,
-                                      _n_chunks(C * S, chunk_size))
-        operands = (states, *streams, pstack, ev, hz)
-        bounds = None
-        if per_cond:
-            cond_bounds = tuple(r_.bounds for r_ in rspecs)
-            horizons = tuple(r_.horizon for r_ in rspecs)
-    finals, (arms, r, c, lam) = _launch_and_read(fn, operands, C, S)
-    cond_params = {
-        n: np.asarray(params.get(n))
-        for n in params.names
-        if np.ndim(params.get(n)) and np.shape(params.get(n))[0] == C
-    } or None
-    res = GridResult(
-        budgets=budgets, seeds=seeds, arms=arms, rewards=r, costs=c,
-        lams=lam, bounds=bounds, params=cond_params,
-        cond_bounds=cond_bounds, horizons=horizons,
-    )
+    with jax.profiler.TraceAnnotation("sweep.run_scenario_grid"):
+        budgets, seeds = _check_grid_args(budgets, seeds, condition_edits)
+        budgets, seeds, flat_b, flat_s = _flatten_grid(budgets, seeds)
+        C, S = len(budgets), len(seeds)
+        params = _merged_scenario_params(
+            scenario_params if scenario_params is not None
+            else scenario_lib.ScenarioParams(), condition_edits, C, S)
+        params = scenario_lib.resolve_params(spec, params)
+        full = params.updated(**scenario_lib.auto_param_values(spec))
+        cond_bounds = horizons = None
+        with jax.profiler.TraceAnnotation("sweep.streams"):
+            if timelines is None:
+                stacks = scenario_lib.build_streams(
+                    cfg, spec, env, seeds, params=params)
+                extras = ()
+            else:
+                tls, per_cond = _normalize_timelines(timelines, C, S)
+                rspecs, stacks, ev, hz = _timeline_grid_operands(
+                    cfg, spec, env, tls, per_cond, seeds, flat_s, params,
+                    batch_size)
+                extras = (ev, hz)
+        with jax.profiler.TraceAnnotation("sweep.states"):
+            states = evaluate.make_states(
+                cfg, env, flat_b, flat_s,
+                priors=priors, n_eff=_per_condition_axis(n_eff, C, S),
+                pacer_enabled=pacer_enabled,
+                active_arms=spec.init_active,
+                hyper=_expand_hyper(hyper, C, S),
+            )
+            if condition_edits is not None:
+                states = _apply_condition_edits(states, condition_edits, S)
+            pstack = _expand_params(full, C, S)
+        states, streams, pstack, extras = _shard_grid(
+            states, stacks, 0, C, devices, pstack, extras=extras)
+        if timelines is None:
+            fn = _cached_scenario_grid_fn(cfg, spec, env, batch_size,
+                                          _n_chunks(C * S, chunk_size))
+            bounds = spec.bounds
+        else:
+            fn = _cached_timeline_grid_fn(cfg, spec, env, batch_size,
+                                          _n_chunks(C * S, chunk_size))
+            bounds = None
+            if per_cond:
+                cond_bounds = tuple(r_.bounds for r_ in rspecs)
+                horizons = tuple(r_.horizon for r_ in rspecs)
+        finals, (arms, r, c, lam) = _launch_and_read(
+            fn, (states, *streams, pstack, *extras), C, S)
+        cond_params = {
+            n: np.asarray(params.get(n))
+            for n in params.names
+            if np.ndim(params.get(n)) and np.shape(params.get(n))[0] == C
+        } or None
+        res = GridResult(
+            budgets=budgets, seeds=seeds, arms=arms, rewards=r, costs=c,
+            lams=lam, bounds=bounds, params=cond_params,
+            cond_bounds=cond_bounds, horizons=horizons,
+        )
     if return_states:
         return res, finals
     return res

@@ -1,8 +1,8 @@
 """The grid fabric's own profiler spans and counters (DESIGN.md §7):
-``sweep.run_grid`` records one span per call and one per host phase
-inside it, each phase's host-device bytes as span arguments, and
-stable names for the grid programs. Results do not depend on whether
-a profiler is recording."""
+``sweep.run_grid`` and ``sweep.run_scenario_grid`` record one span per
+call and one per host phase inside it, each phase's host-device bytes
+as span arguments, and stable names for the grid programs. Results do
+not depend on whether a profiler is recording."""
 import contextlib
 
 import jax
@@ -129,6 +129,58 @@ def test_byte_counters_equal_the_shapes(layout, env, envs, tmp_path):
     assert got["sweep.place"][0].args == {"h2d_bytes": C * S * T * row,
                                           "d2h_bytes": S * T * row}
     out = sum(C * S * T * np.dtype(a.dtype).itemsize
+              for a in (grid.arms, grid.rewards, grid.costs, grid.lams))
+    assert got["sweep.readback"][0].args == {"d2h_bytes": out}
+
+
+# One timeline per condition: the event at step 16, and at step 30 with
+# the horizon cut to 40 of the spec's 48 steps.
+TIMELINES = [Timeline((16,)), Timeline((30,), horizon=40)]
+
+
+def _scenario_grid(env, timelines=TIMELINES):
+    return sweep.run_scenario_grid(CFG, SPEC, env, BUDGETS, seeds=SEEDS,
+                                   timelines=timelines)
+
+
+@pytest.mark.parametrize("timelines", [TIMELINES, None],
+                         ids=["timeline", "segmented"])
+def test_run_scenario_grid_records_its_phases_nested_and_in_order(
+        timelines, env, tmp_path):
+    with profiled(tmp_path) as spans:
+        _scenario_grid(env, timelines)
+    assert [s.name for s in spans] == ["sweep.run_scenario_grid", *PHASES]
+    call = spans[0]
+    ends = [call.start]
+    for sp in spans[1:]:
+        assert call.start <= sp.start <= sp.end <= call.end, sp.name
+        assert sp.start >= ends[-1], f"{sp.name} starts before the last ends"
+        ends.append(sp.end)
+
+
+def test_scenario_grid_phases_cover_the_call(env, tmp_path):
+    _scenario_grid(env)
+    with profiled(tmp_path) as spans:
+        _scenario_grid(env)
+    call, *phases = spans
+    assert call.name == "sweep.run_scenario_grid"
+    covered = sum(p.end - p.start for p in phases)
+    assert covered >= 0.9 * (call.end - call.start)
+
+
+def test_scenario_grid_byte_counters_equal_the_shapes(env, tmp_path):
+    """The timeline path builds its per-element streams on the host and
+    sends them once, in ``place``, with the payload stack (the
+    auto-lifted price multiplier), the event steps and the horizons."""
+    n, T, E = len(BUDGETS) * len(SEEDS), SPEC.horizon, len(SPEC.events)
+    with profiled(tmp_path) as spans:
+        grid = _scenario_grid(env)
+    got = _by_name(spans)
+    assert "h2d_bytes" not in got["sweep.streams"][0].args
+    assert got["sweep.place"][0].args == {
+        "h2d_bytes": n * T * _row_bytes(env) + 4 * n + 4 * n * E + 4 * n,
+        "d2h_bytes": 0}
+    out = sum(n * T * np.dtype(a.dtype).itemsize
               for a in (grid.arms, grid.rewards, grid.costs, grid.lams))
     assert got["sweep.readback"][0].args == {"d2h_bytes": out}
 

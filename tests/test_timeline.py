@@ -408,6 +408,21 @@ class TestVectorizedStreamRebuild:
         tls = [Timeline((80,)), Timeline((30,), horizon=100)]
         self._check_equal(spec, env, tls, [(0, 1), (0, 1)], pad_to=160)
 
+    @pytest.mark.parametrize("mix", [False, True])
+    def test_both_paths_return_host_arrays(self, env, mix):
+        """One contract on both paths: host arrays, sent and counted by
+        the fabric's ``place``."""
+        events = ((TrafficMixShift(80, tuple(
+            3.0 if f == 1 else 0.25 for f in range(9))),) if mix
+            else (QualityShift(80, MISTRAL, 0.7),))
+        spec = ScenarioSpec(horizon=160, events=events, stream_seed_base=946)
+        assert scenario.timeline_streams_vectorizable(spec) is not mix
+        rspecs = [retime(spec, Timeline((80,))), retime(spec, Timeline((30,)))]
+        got = scenario.build_timeline_streams(
+            CFG, spec, env, rspecs, [(0,), (1,)], pad_to=160)
+        for a in got:
+            assert isinstance(a, np.ndarray), type(a)
+
 
 class TestMonteCarlo:
     SPEC = ScenarioSpec(horizon=120, events=(

@@ -89,3 +89,42 @@ def test_linucb_step_vmapped_compiles(one_chip, no_persistent_cache):
         jax.vmap(lambda *a: linucb_step(*a, interpret=False)),
         *_step_shapes(spec, B, K, d))
     assert "tpu_custom_call" in text
+
+
+def test_timeline_grid_program_compiles(one_chip, no_persistent_cache):
+    """The masked timeline grid program of the ``paper3_mc1024`` cell at
+    its own size: 1,024 elements x 1,824 steps, 8 arm slots, d = 26, a
+    silent price cut, a quality shift and a cold ``AddArm`` with forced
+    pulls, each event's step an operand of every element."""
+    from repro.core import evaluate, scenario, simulator, sweep
+    from repro.core.scenario import (AddArm, PriceChange, QualityShift,
+                                     ScenarioSpec)
+    from repro.core.types import RouterConfig
+
+    N, T, d = 1024, 1824, 26
+    b = simulator.make_benchmark(
+        seed=0, splits={"train": 128, "val": 16, "test": 64})
+    env = simulator.extend_with_flash(b.test, "good_cheap")
+    cfg = RouterConfig(d=d, max_arms=8, forced_pulls=20)
+    spec = ScenarioSpec(horizon=T, events=(
+        PriceChange(152, 2, 1 / 56), QualityShift(152, 1, 0.75),
+        AddArm(152, 3)), init_active=3)
+    fn = sweep._cached_timeline_grid_fn(cfg, spec, env, None)
+    priors = evaluate.fit_warmup_priors(cfg, b.train) + [None]
+    states = evaluate.make_states(cfg, env, 6.6e-4, (0,), priors=priors,
+                                  n_eff=1164.0, active_arms=3)
+    params = scenario.ScenarioParams(**scenario.auto_param_values(spec))
+
+    def spec_of(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    operands = (
+        jax.tree.map(lambda l: spec_of((N,) + l.shape[1:], l.dtype), states),
+        spec_of((N, T, d)), spec_of((N, T, 8)), spec_of((N, T, 8)),
+        jax.tree.map(lambda l: spec_of((N,) + l.shape), params),
+        spec_of((N, len(spec.events)), jnp.int32), spec_of((N,), jnp.int32))
+    compiled = fn.lower(*operands).compile()
+    assert "jit_timeline_grid_program" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < 16e9
