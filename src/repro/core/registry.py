@@ -9,6 +9,11 @@ so the jitted routing step never recompiles across portfolio changes.
   * heuristic prior  — n_eff pseudo-observations at isotropic uncertainty
                        with a bias-only reward prediction (§3.4);
   * offline prior    — scaled offline sufficient statistics (warmup.py).
+The first two make A a scaled identity s*I, so A^-1 = I/s and
+theta = b/s are written in closed form; only the offline prior, a full
+matrix, is inverted. Under ``jax.vmap`` the scenario engine's
+``lax.cond`` around an edit becomes a select that runs the edit on every
+step, so an LU here would be paid per step and per element.
 
 A newly added arm can be given a forced-exploration burn-in
 (cfg.forced_pulls unconditional routes, §4.5), after which UCB takes over.
@@ -48,15 +53,22 @@ def _replace(state: RouterState, **kw) -> RouterState:
     return dataclasses.replace(state, **kw)
 
 
+def _isotropic(d: int, s):
+    """(A, A^-1) of the scaled identity A = s*I, the inverse in closed form."""
+    eye = jnp.eye(d, dtype=jnp.float32)
+    return eye * s, eye / s
+
+
 def heuristic_prior(cfg: RouterConfig, hp, n_eff: float, bias_reward: float):
     """§3.4: for models absent from offline data — n_eff pseudo-observations
     at isotropic uncertainty with a bias-only reward prediction. Assumes the
     bias coordinate is the last feature (features.py appends it). ``hp``
-    supplies the (traced) ridge weight lambda0."""
+    supplies the (traced) ridge weight lambda0. Returns (s, b) with
+    A = s*I."""
     d = cfg.d
-    A = jnp.eye(d, dtype=jnp.float32) * (hp.lambda0 + n_eff / d)
+    s = hp.lambda0 + n_eff / d
     b = jnp.zeros((d,), jnp.float32).at[d - 1].set(bias_reward * n_eff / d)
-    return A, b
+    return s, b
 
 
 def add_arm(
@@ -84,13 +96,15 @@ def add_arm(
     if prior is not None:
         ne = n_eff if traced_ne else (n_eff or 1.0)
         A, b = warmup_lib.scale_prior(cfg, hp, prior, ne)
-    elif n_eff is not None and (traced_ne or n_eff > 0):
-        A, b = heuristic_prior(cfg, hp, n_eff, bias_reward)
+        A_inv = jnp.linalg.inv(A)
+        theta = A_inv @ b
     else:
-        A = jnp.eye(d, dtype=jnp.float32) * hp.lambda0
-        b = jnp.zeros((d,), jnp.float32)
-    A_inv = jnp.linalg.inv(A)
-    theta = A_inv @ b
+        if n_eff is not None and (traced_ne or n_eff > 0):
+            s, b = heuristic_prior(cfg, hp, n_eff, bias_reward)
+        else:
+            s, b = hp.lambda0, jnp.zeros((d,), jnp.float32)
+        A, A_inv = _isotropic(d, s)
+        theta = b / s
     c_t = log_normalized_cost(jnp.asarray(price_per_1k, jnp.float32), hp)
     state = _replace(
         state,
@@ -118,12 +132,12 @@ def delete_arm(cfg: RouterConfig, state: RouterState, slot: int) -> RouterState:
     the same slot starts clean; any in-flight forced exploration of the slot
     is cancelled."""
     d = cfg.d
-    lambda0 = state.hyper.lambda0
+    A, A_inv = _isotropic(d, state.hyper.lambda0)
     cancel = state.force_arm == slot
     return _replace(
         state,
-        A=state.A.at[slot].set(jnp.eye(d, dtype=jnp.float32) * lambda0),
-        A_inv=state.A_inv.at[slot].set(jnp.eye(d, dtype=jnp.float32) / lambda0),
+        A=state.A.at[slot].set(A),
+        A_inv=state.A_inv.at[slot].set(A_inv),
         b=state.b.at[slot].set(jnp.zeros((d,), jnp.float32)),
         theta=state.theta.at[slot].set(jnp.zeros((d,), jnp.float32)),
         active=state.active.at[slot].set(False),

@@ -95,7 +95,8 @@ def test_timeline_grid_program_compiles(one_chip, no_persistent_cache):
     """The masked timeline grid program of the ``paper3_mc1024`` cell at
     its own size: 1,024 elements x 1,824 steps, 8 arm slots, d = 26, a
     silent price cut, a quality shift and a cold ``AddArm`` with forced
-    pulls, each event's step an operand of every element."""
+    pulls, each event's step an operand of every element. The cold arm's
+    inverse is closed form, so the program holds no LU custom call."""
     from repro.core import evaluate, scenario, simulator, sweep
     from repro.core.scenario import (AddArm, PriceChange, QualityShift,
                                      ScenarioSpec)
@@ -124,7 +125,13 @@ def test_timeline_grid_program_compiles(one_chip, no_persistent_cache):
         jax.tree.map(lambda l: spec_of((N,) + l.shape), params),
         spec_of((N, len(spec.events)), jnp.int32), spec_of((N,), jnp.int32))
     compiled = fn.lower(*operands).compile()
-    assert "jit_timeline_grid_program" in compiled.as_text()
+    text = compiled.as_text()
+    assert "jit_timeline_grid_program" in text
+    # The cold AddArm's A^-1 is written in closed form: no batched LU
+    # runs inside the scan (the edit's select runs it on every step).
+    for target in ("LuDecompositionBlock", "InvertDiagBlocksLowerTriangular",
+                   "InvertDiagBlocksUpperTriangular"):
+        assert target not in text, target
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes) < 16e9
