@@ -45,11 +45,8 @@ from repro.core.montecarlo import (  # noqa: F401
 )
 from repro.core.sweep import (  # noqa: F401
     GridResult,
-    chain_edits,
-    hyper_edit,
     run_grid,
     run_scenario_grid,
-    warmup_edit,
 )
 from repro.core.warmup import (  # noqa: F401
     apply_warmup,

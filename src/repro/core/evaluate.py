@@ -118,8 +118,7 @@ class RunResult:
 
 def pad_priors(cfg: RouterConfig, priors: Sequence[ArmPrior | None]):
     """Pad a per-arm prior list out to ``max_arms`` slots (the layout
-    ``warmup.apply_warmup`` expects); shared with sweep.warmup_edit so
-    per-condition warm starts load the slots ``make_states`` loads."""
+    ``warmup.apply_warmup`` expects)."""
     pad = cfg.max_arms - len(priors)
     assert pad >= 0, (len(priors), cfg.max_arms)
     return list(priors) + [None] * pad
@@ -240,8 +239,8 @@ def make_states(
     scalar, or one pseudo-count per stacked state (the knee grid derives
     n_eff from each cell's gamma via Eq. 13), applied inside the same
     program — all warm or all cold; a mixed stack would need the warmup
-    branch to be data-dependent (use per-condition ``condition_edits``
-    for that instead).
+    branch to be data-dependent (run the warm and the cold conditions as
+    two grids instead).
 
     ``tenants`` attaches a per-tenant pacer table (DESIGN.md §15): one
     shared (T,) ``tenancy.TenantTable`` copied into every state, or one
@@ -267,7 +266,7 @@ def make_states(
         raise ValueError(
             "mixed warm/cold n_eff in one stack: apply_warmup at n_eff=0 "
             "is not a no-op, so warm-vs-cold cannot share the vmapped "
-            "branch — stack it via condition_edits instead")
+            "branch — run the warm and the cold conditions as two grids")
     if ne.ndim and ne.shape != (n,):
         raise ValueError(
             f"n_eff must be a scalar or one value per state; got shape "

@@ -8,12 +8,11 @@ Python around a per-condition jitted call, paying a dispatch (and, across
 configs, a retrace) per cell. This module evaluates the entire grid in
 one jitted, device-sharded call:
 
-  * the condition axis is stacked into *state leaves* — the budget
-    ceiling lives in ``PacerState.budget`` (evaluate.make_states accepts
-    one budget per stacked state), and any other state-leaf knob can be
-    stacked via ``condition_edits`` (pure ``RouterState -> RouterState``
-    functions, e.g. ``pacer.set_budget`` or a pacer-disable flip, applied
-    per condition before the run);
+  * the condition axis is stacked into *operands* — the budget ceiling
+    lives in ``PacerState.budget`` (evaluate.make_states accepts one
+    budget per stacked state), and hyper-parameters, warm-start n_eff,
+    scenario payloads and tenant tables arrive as scalar, per-condition
+    ``(C,)`` or per-element ``(C·S,)`` stacks;
 
   * the (condition, seed) grid is flattened to one leading axis of size
     N = C x S, ``jax.vmap``-ed over the existing per-seed program —
@@ -37,12 +36,11 @@ one jitted, device-sharded call:
 
 Hyper-parameters are state leaves too (DESIGN.md §9): ``RouterState``
 carries a ``HyperParams`` pytree, so a whole (α, γ) grid stacks on the
-condition axis via ``hyper_edit``/``condition_edits`` — bench_knee's
-full (α x γ x budget x seed) selection grid is ONE fabric call. And
-scenario event *payloads* are data as well (DESIGN.md §10): a
-``ScenarioSpec`` whose payloads are ``scenario.Param`` references plus
-a ``scenario_params=`` stack (or per-condition ``param_edit`` entries)
-fuses a whole spec *family* — price cuts at several magnitudes,
+condition axis as ``hyper=`` leaves — bench_knee's full (α x γ x
+budget x seed) selection grid is ONE fabric call. And scenario event
+*payloads* are data as well (DESIGN.md §10): a ``ScenarioSpec`` whose
+payloads are ``scenario.Param`` references plus a ``scenario_params=``
+stack fuses a whole spec *family* — price cuts at several magnitudes,
 regressions to several quality targets — into one
 ``run_scenario_grid`` call. Knobs that remain *trace constants* — the
 ``Statics`` (``d``, ``max_arms``, ``backend``, ``dt_max``,
@@ -60,13 +58,13 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
-from typing import Callable, Optional, Sequence
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import evaluate, router, tenancy, warmup
+from repro.core import evaluate, tenancy
 from repro.core import scenario as scenario_lib
 from repro.core import types as types_lib
 from repro.core.simulator import Environment
@@ -121,11 +119,11 @@ class GridResult:
             yield b, self.condition(i)
 
 
-def _check_grid_args(budgets, seeds, condition_edits):
-    """Explicit ValueErrors for degenerate grids — an empty axis or a
-    misaligned edit list would otherwise surface as a cryptic reshape /
-    vmap / mesh failure deep inside the fabric. Materializes (and
-    returns) the axes exactly once so one-shot iterables stay valid."""
+def _check_grid_args(budgets, seeds):
+    """Explicit ValueErrors for degenerate grids — an empty axis would
+    otherwise surface as a cryptic reshape / vmap / mesh failure deep
+    inside the fabric. Materializes (and returns) the axes exactly once
+    so one-shot iterables stay valid."""
     budgets, seeds = tuple(budgets), tuple(seeds)
     if not budgets:
         raise ValueError(
@@ -133,11 +131,6 @@ def _check_grid_args(budgets, seeds, condition_edits):
     if not seeds:
         raise ValueError(
             "seeds is empty: the grid needs at least one seed")
-    if condition_edits is not None and len(condition_edits) != len(budgets):
-        raise ValueError(
-            f"condition_edits has {len(condition_edits)} entries but the "
-            f"grid has {len(budgets)} conditions (one edit — or "
-            "None — per budget)")
     return budgets, seeds
 
 
@@ -273,20 +266,6 @@ def _shard_grid(states: RouterState, streams, stream_axes, C, devices,
     return states, streams, params, extras
 
 
-def _apply_condition_edits(
-    states: RouterState,
-    condition_edits: Sequence[Optional[Callable[[RouterState], RouterState]]],
-    S: int,
-) -> RouterState:
-    """Apply per-condition pure state edits to the flattened stack (one
-    vmapped call per condition; host-side, once per grid)."""
-    parts = []
-    for c, edit in enumerate(condition_edits):
-        block = jax.tree.map(lambda l: l[c * S:(c + 1) * S], states)
-        parts.append(block if edit is None else jax.vmap(edit)(block))
-    return jax.tree.map(lambda *ls: jnp.concatenate(ls), *parts)
-
-
 def _n_chunks(n: int, chunk_size) -> int:
     """Validate a ``chunk_size`` knob against the flattened grid size."""
     if chunk_size is None:
@@ -398,97 +377,6 @@ def _cached_grid_fn_tenants(statics, stream_axes, batch_size, n_chunks=1):
         (stream_axes == 0,) * 3 + (True,))
 
 
-# ---------------------------------------------------------------------------
-# Condition-edit helpers (DESIGN.md §7 stacking rules)
-# ---------------------------------------------------------------------------
-
-
-def hyper_edit(hyper: Optional[HyperParams] = None, **overrides):
-    """A condition edit pinning hyper-parameter leaves — the way a
-    (α, γ, ...) grid joins the fused condition axis (DESIGN.md §9).
-
-    ``sweep.run_grid(cfg, env, budgets, condition_edits=[
-        sweep.hyper_edit(alpha=0.05, gamma=0.997), ...])``
-    """
-    if hyper is not None:
-        hyper.validate()
-    if overrides:
-        HyperParams.validate_fields(**overrides)
-
-    def edit(st: RouterState) -> RouterState:
-        return types_lib.with_hyperparams(st, hyper=hyper, **overrides)
-
-    return edit
-
-
-def warmup_edit(cfg: RouterConfig, priors, n_eff: float):
-    """A condition edit applying the §3.4 warm start — per-condition
-    ``n_eff`` (e.g. derived from gamma via Eq. 13) stacked on the grid
-    axis. The same math as ``make_states(priors=..., n_eff=...)``, run
-    eagerly: where ``make_states``' compiled program contracts a
-    multiply-add into one rounding, ``b`` and ``theta`` can differ from
-    its states in the last bit, too little to move a decision in the
-    grids the tests pin (tests/test_hyperparams.py)."""
-    padded = evaluate.pad_priors(cfg, list(priors))
-
-    def edit(st: RouterState) -> RouterState:
-        return warmup.apply_warmup(cfg, st, padded, n_eff)
-
-    return edit
-
-
-def param_edit(**overrides):
-    """A condition edit pinning scenario payload leaves — the way a
-    *payload* axis (price multiplier, quality target, ...) joins a
-    scenario grid's fused condition axis (DESIGN.md §10), mirroring
-    ``hyper_edit`` for ``HyperParams``.
-
-    ``sweep.run_scenario_grid(cfg, spec, env, budgets, condition_edits=[
-        sweep.chain_edits(sweep.hyper_edit(alpha=a), sweep.param_edit(mult=m))
-        for a, m in cells])``
-
-    The state part is the identity: payload leaves are not
-    ``RouterState`` leaves but ``ScenarioParams`` operands, so
-    ``run_scenario_grid`` folds the per-condition overrides into the
-    stacked params instead (``run_grid`` has no scenario payloads and
-    rejects them).
-    """
-
-    def edit(st: RouterState) -> RouterState:
-        return st
-
-    # Normalize through ScenarioParams so payload kinds (floats, weight
-    # vectors, ArmPrior -> packed (d, d+1) leaves) behave identically to
-    # the scenario_params= path.
-    normalized = scenario_lib.ScenarioParams(**overrides)
-    edit.param_overrides = {n: normalized.get(n) for n in normalized.names}
-    return edit
-
-
-def chain_edits(*edits):
-    """Compose condition edits left-to-right (``None`` entries skipped);
-    returns None when nothing remains, matching ``condition_edits``'
-    no-op convention. ``param_edit`` payload overrides carried by the
-    inputs are merged (rightmost wins) onto the composite."""
-    live = tuple(e for e in edits if e is not None)
-    if not live:
-        return None
-    if len(live) == 1:
-        return live[0]
-
-    def edit(st: RouterState) -> RouterState:
-        for e in live:
-            st = e(st)
-        return st
-
-    merged = {}
-    for e in live:
-        merged.update(getattr(e, "param_overrides", {}))
-    if merged:
-        edit.param_overrides = merged
-    return edit
-
-
 def run_grid(
     cfg: RouterConfig,
     env: Environment | Sequence[Environment],
@@ -500,7 +388,6 @@ def run_grid(
     pacer_enabled: bool = True,
     shuffle: bool = True,
     batch_size: Optional[int] = None,
-    condition_edits: Optional[Sequence[Optional[Callable]]] = None,
     devices=None,
     return_states: bool = False,
     hyper: Optional[HyperParams] = None,
@@ -512,17 +399,16 @@ def run_grid(
 
     Semantics per condition match ``evaluate.run(cfg, env, budgets[c],
     seeds=seeds, ...)`` bit-for-bit: same per-seed shuffles, same initial
-    states, same scan bodies. ``condition_edits`` optionally applies one
-    extra pure state edit per condition (aligned with ``budgets``) for
-    state-leaf axes beyond the ceiling.
+    states, same scan bodies.
 
-    ``hyper`` leaves and ``n_eff`` may be per-condition (C,) vectors
-    (DESIGN.md §9): they are repeated S times onto the flattened stack
-    and applied inside ``make_states``' one cached compiled program
-    (keyed on the stack's structure, every value an operand) — the
-    cheap way to put an (α, γ, n_eff) grid on the condition axis
-    (``condition_edits`` pays one eager vmapped edit per condition
-    instead, which dominates wall clock on wide grids).
+    ``hyper`` leaves and ``n_eff`` may each be a scalar, a per-condition
+    ``(C,)`` vector or a per-element ``(C*S,)`` stack (DESIGN.md §9): a
+    (C,) vector is repeated S times onto the flattened stack, and all of
+    it is applied inside ``make_states``' one cached compiled program
+    (keyed on the stack's structure, every value an operand) — the way
+    an (α, γ, n_eff) grid joins the condition axis. ``pacer_enabled``
+    and warm versus cold priors are one structure per call: run such
+    conditions as separate grids.
 
     ``devices`` defaults to ``jax.devices()``; the flattened C*S axis is
     sharded over the largest device count dividing it.
@@ -543,19 +429,13 @@ def run_grid(
     ``batch_size`` (tenant routing is a batched-data-plane feature).
     """
     with jax.profiler.TraceAnnotation("sweep.run_grid"):
-        budgets, seeds = _check_grid_args(budgets, seeds, condition_edits)
+        budgets, seeds = _check_grid_args(budgets, seeds)
         if (tenant_tables is None) != (tenant_ids is None):
             raise ValueError("pass tenant_tables and tenant_ids together")
         if tenant_tables is not None and not batch_size:
             raise ValueError(
                 "tenant grids need batch_size: tenant routing is a batched-"
                 "data-plane feature (DESIGN.md §15)")
-        if condition_edits is not None and any(
-                getattr(e, "param_overrides", None) for e in condition_edits):
-            raise ValueError(
-                "param_edit pins scenario payload leaves; use it with "
-                "run_scenario_grid (run_grid evaluates plain streams with "
-                "no scenario events)")
         budgets, seeds, flat_b, flat_s = _flatten_grid(budgets, seeds)
         C, S = len(budgets), len(seeds)
         # Deliberate host->device staging: stream tensors and the stacked
@@ -582,9 +462,6 @@ def run_grid(
                     hyper=_expand_hyper(hyper, C, S),
                     tenants=_expand_tenants(tenant_tables, C, S),
                 )
-                if condition_edits is not None:
-                    states = _apply_condition_edits(states, condition_edits,
-                                                    S)
                 extras = ()
                 if tenant_ids is not None:
                     tids = np.asarray(tenant_ids, np.int32)
@@ -604,14 +481,14 @@ def run_grid(
                 states, (xs, rmat, cmat), stream_axes, C, devices,
                 extras=extras)
 
-        if tenant_ids is not None:
-            fn = _cached_grid_fn_tenants(cfg.statics, stream_axes, batch_size,
-                                         _n_chunks(C * S, chunk_size))
-        else:
-            fn = _cached_grid_fn(cfg.statics, stream_axes, batch_size,
-                                 _n_chunks(C * S, chunk_size))
+        def program():
+            cached = (_cached_grid_fn if tenant_ids is None
+                      else _cached_grid_fn_tenants)
+            return cached(cfg.statics, stream_axes, batch_size,
+                          _n_chunks(C * S, chunk_size))
+
         finals, (arms, r, c, lam) = _launch_and_read(
-            fn, (states, *streams, *extras), C, S)
+            program, (states, *streams, *extras), C, S)
         res = GridResult(budgets=budgets, seeds=seeds, arms=arms, rewards=r,
                          costs=c, lams=lam)
     if return_states:
@@ -619,20 +496,21 @@ def run_grid(
     return res
 
 
-def _launch_and_read(fn, operands, C: int, S: int):
+def _launch_and_read(program, operands, C: int, S: int):
     """Run a compiled grid program and read its per-step traces back as
-    (C, S, T) host arrays: ``sweep.launch`` (the dispatch),
-    ``sweep.wait`` (the device running it) and ``sweep.readback`` (the
-    copies, with their ``d2h_bytes``). Returns (final states, (arms,
-    rewards, costs, lams))."""
+    (C, S, T) host arrays: ``sweep.launch`` (``program()``, which looks
+    the cached program up, and its dispatch), ``sweep.wait`` (the device
+    running it) and ``sweep.readback`` (the copies, with their
+    ``d2h_bytes``). Returns (final states, (arms, rewards, costs,
+    lams))."""
     with jax.profiler.TraceAnnotation("sweep.launch"):
-        finals, outs = fn(*operands)
+        finals, outs = program()(*operands)
     with jax.profiler.TraceAnnotation("sweep.wait"):
         jax.block_until_ready(outs)
-    with jax.profiler.TraceAnnotation(
-            "sweep.readback", d2h_bytes=sum(_d2h_nbytes(o) for o in outs)):
-        outs = tuple(np.asarray(o).reshape(C, S, -1) for o in outs)
-    return finals, outs
+    with jax.profiler.TraceAnnotation("sweep.readback") as span:
+        host = tuple(np.asarray(o).reshape(C, S, -1) for o in outs)
+        span.set_metadata(d2h_bytes=sum(_d2h_nbytes(o) for o in outs))
+    return finals, host
 
 
 # ---------------------------------------------------------------------------
@@ -641,43 +519,6 @@ def _launch_and_read(fn, operands, C: int, S: int):
 
 _SCEN_CACHE: collections.OrderedDict = collections.OrderedDict()
 _SCEN_CACHE_MAX = 64
-
-
-def _merged_scenario_params(base, condition_edits, C: int, S: int):
-    """Fold per-condition ``param_edit`` overrides (riding
-    ``condition_edits``) into the base ``ScenarioParams``: any name
-    touched by an override becomes a (C,)-stacked leaf whose untouched
-    conditions fall back to the base leaf."""
-    over = [dict(getattr(e, "param_overrides", {}) or {})
-            for e in (condition_edits or ())]
-    names = set().union(*over) if over else set()
-    if not names:
-        return base
-    base_vals = dict(zip(base.names, (base.get(n) for n in base.names)))
-    merged = dict(base_vals)
-    for name in sorted(names):
-        stacked = []
-        for c in range(C):
-            if name in over[c]:
-                stacked.append(np.asarray(over[c][name], np.float32))
-                continue
-            if name not in base_vals:
-                raise ValueError(
-                    f"param_edit sets {name!r} for some conditions but "
-                    f"condition {c} has no override and scenario_params "
-                    "provides no base value")
-            v = np.asarray(base_vals[name])
-            if v.ndim and v.shape[0] == C * S and C != C * S:
-                raise ValueError(
-                    f"param_edit overrides {name!r} but the base leaf is "
-                    f"a pre-flattened ({C * S},) stack: a per-condition "
-                    "override of a per-element leaf is ambiguous — pass "
-                    f"a (C,) = ({C},) stacked base leaf instead")
-            # A base leaf already stacked per condition contributes its
-            # c-th entry; a shared leaf contributes itself.
-            stacked.append(v[c] if (v.ndim and v.shape[0] == C) else v)
-        merged[name] = np.stack(stacked)
-    return scenario_lib.ScenarioParams(**merged)
 
 
 def _expand_params(params, C: int, S: int):
@@ -805,7 +646,6 @@ def run_scenario_grid(
     devices=None,
     return_states: bool = False,
     hyper: Optional[HyperParams] = None,
-    condition_edits: Optional[Sequence[Optional[Callable]]] = None,
     scenario_params: Optional["scenario_lib.ScenarioParams"] = None,
     chunk_size: Optional[int] = None,
     timelines=None,
@@ -818,14 +658,15 @@ def run_scenario_grid(
     ceiling from its boundary onward, in every condition — the grid axis
     is the *initial* operating point.
 
+    ``hyper`` leaves and ``n_eff`` stack on the condition axis as in
+    ``run_grid``: scalars, ``(C,)`` or ``(C*S,)`` stacks.
+
     ``scenario_params`` resolves ``Param`` payload references in the
     spec (DESIGN.md §10): leaves may be scalars (shared), ``(C,)``
     stacks aligned with ``budgets`` (a *payload* condition axis — the
     way a whole spec family, e.g. price cuts at several magnitudes,
     fuses into this one compiled grid), or pre-flattened ``(C*S,)``
-    stacks. Per-condition ``sweep.param_edit(...)`` entries on
-    ``condition_edits`` (composable with ``hyper_edit`` via
-    ``chain_edits``) are folded into the same stacked leaves.
+    stacks.
 
     ``chunk_size`` scans the flattened grid chunk-by-chunk inside the
     compiled program exactly as in ``run_grid`` (bit-identical results,
@@ -840,20 +681,17 @@ def run_scenario_grid(
     timeline assignment re-entering ONE compiled program (the scenario
     Monte Carlo substrate). Per-condition timelines record effective
     ``cond_bounds``/``horizons`` on the result so ``condition(i)`` trims
-    padding; composes with ``condition_edits``/``scenario_params``/
+    padding; composes with ``hyper``/``n_eff``/``scenario_params``/
     ``chunk_size`` and both data planes unchanged.
     """
     with jax.profiler.TraceAnnotation("sweep.run_scenario_grid"):
-        budgets, seeds = _check_grid_args(budgets, seeds, condition_edits)
+        budgets, seeds = _check_grid_args(budgets, seeds)
         budgets, seeds, flat_b, flat_s = _flatten_grid(budgets, seeds)
         C, S = len(budgets), len(seeds)
-        params = _merged_scenario_params(
-            scenario_params if scenario_params is not None
-            else scenario_lib.ScenarioParams(), condition_edits, C, S)
-        params = scenario_lib.resolve_params(spec, params)
-        full = params.updated(**scenario_lib.auto_param_values(spec))
         cond_bounds = horizons = None
         with jax.profiler.TraceAnnotation("sweep.streams"):
+            params = scenario_lib.resolve_params(spec, scenario_params)
+            full = params.updated(**scenario_lib.auto_param_values(spec))
             if timelines is None:
                 stacks = scenario_lib.build_streams(
                     cfg, spec, env, seeds, params=params)
@@ -872,24 +710,25 @@ def run_scenario_grid(
                 active_arms=spec.init_active,
                 hyper=_expand_hyper(hyper, C, S),
             )
-            if condition_edits is not None:
-                states = _apply_condition_edits(states, condition_edits, S)
             pstack = _expand_params(full, C, S)
         states, streams, pstack, extras = _shard_grid(
             states, stacks, 0, C, devices, pstack, extras=extras)
+
+        def program():
+            cached = (_cached_scenario_grid_fn if timelines is None
+                      else _cached_timeline_grid_fn)
+            return cached(cfg, spec, env, batch_size,
+                          _n_chunks(C * S, chunk_size))
+
+        finals, (arms, r, c, lam) = _launch_and_read(
+            program, (states, *streams, pstack, *extras), C, S)
         if timelines is None:
-            fn = _cached_scenario_grid_fn(cfg, spec, env, batch_size,
-                                          _n_chunks(C * S, chunk_size))
             bounds = spec.bounds
         else:
-            fn = _cached_timeline_grid_fn(cfg, spec, env, batch_size,
-                                          _n_chunks(C * S, chunk_size))
             bounds = None
             if per_cond:
                 cond_bounds = tuple(r_.bounds for r_ in rspecs)
                 horizons = tuple(r_.horizon for r_ in rspecs)
-        finals, (arms, r, c, lam) = _launch_and_read(
-            fn, (states, *streams, pstack, *extras), C, S)
         cond_params = {
             n: np.asarray(params.get(n))
             for n in params.names

@@ -344,9 +344,8 @@ def with_hyperparams(
     """Retune a state's hyper-parameters in place (pure; jit/vmap-safe).
 
     Either a full replacement ``hyper`` or field ``overrides`` on the
-    state's current values. The per-condition ``hyper_edit`` of the sweep
-    fabric, the scenario engine's ``HyperShift`` event and
-    ``PortfolioServer.set_hyperparams`` all lower to this.
+    state's current values. The scenario engine's ``HyperShift`` event
+    and ``PortfolioServer.set_hyperparams`` lower to this.
     """
     hp = state.hyper if hyper is None else hyper.validate().as_leaves()
     if overrides:

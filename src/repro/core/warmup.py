@@ -37,7 +37,7 @@ def scale_prior(cfg: RouterConfig, hp: HyperParams, prior: ArmPrior,
       b   = s * b_off + lambda0 * theta_off   (mean-preserving correction)
 
     ``hp`` supplies lambda0 — a traced hyper leaf, so warm starts compose
-    inside jitted programs (sweep condition edits, scenario AddArm).
+    inside jitted programs (make_states' state builder, scenario AddArm).
     """
     d = cfg.d
     assert prior.A_off.shape == (d, d), prior.A_off.shape

@@ -190,40 +190,28 @@ class TestOneProgramAcrossHypers:
                          env, 6.6e-4, seeds=SEEDS)
         assert router.TRACE_COUNT[0] == before, "hyper change retraced"
 
-    def test_grid_hyper_condition_axis_bitwise(self, env, priors):
-        """(α, γ) stacked on the fused condition axis == per-cell looped
-        runs, bit for bit — including a per-cell warm start."""
-        cfg = RouterConfig()
-        cells = ((0.01, 0.997), (0.1, 0.999))
-        n_eff = 1164.0
-        edits = [sweep.chain_edits(
-            sweep.hyper_edit(alpha=a, gamma=g),
-            sweep.warmup_edit(cfg, priors, n_eff)) for a, g in cells]
-        before = sweep.TRACE_COUNT[0]
-        grid = sweep.run_grid(cfg, env, (6.6e-4, 6.6e-4), seeds=SEEDS,
-                              condition_edits=edits)
-        assert sweep.TRACE_COUNT[0] - before <= 1
-        for i, (a, g) in enumerate(cells):
-            res = evaluate.run(
-                cfg, env, 6.6e-4, seeds=SEEDS, priors=priors, n_eff=n_eff,
-                hyper=HyperParams(alpha=a, gamma=g))
-            _assert_bitwise(grid.condition(i), res)
-
-    def test_grid_per_condition_hyper_and_neff_vectors(self, env, priors):
-        """The cheap stacking path (bench_knee's): per-condition (C,)
-        hyper leaves + a per-condition n_eff vector expand onto the
-        flattened axis inside make_states' single vmap — bit-identical
-        to per-cell looped runs."""
+    @pytest.mark.parametrize("layout", ["per_condition", "per_element"])
+    def test_grid_hyper_condition_axis_bitwise(self, layout, env, priors):
+        """(α, γ) and a per-cell warm start's n_eff (Eq. 13) stacked on
+        the fused condition axis, as (C,) vectors or as (C*S,) stacks
+        repeated per seed, expand onto the flattened axis inside
+        make_states' one compiled program: one fabric trace, and each
+        condition bit-identical to its looped run."""
         from repro.core import warmup
         cfg = RouterConfig()
         cells = ((0.01, 0.997), (0.1, 0.999))
         n_effs = [warmup.t_adapt_to_n_eff(500.0, g) for _, g in cells]
-        hyp = HyperParams(
-            alpha=np.asarray([a for a, _ in cells], np.float32),
-            gamma=np.asarray([g for _, g in cells], np.float32))
+        reps = 1 if layout == "per_condition" else len(SEEDS)
+
+        def stack(values):
+            return np.repeat(np.asarray(values, np.float32), reps)
+
+        hyp = HyperParams(alpha=stack([a for a, _ in cells]),
+                          gamma=stack([g for _, g in cells]))
+        before = sweep.TRACE_COUNT[0]
         grid = sweep.run_grid(cfg, env, (6.6e-4, 1.9e-3), seeds=SEEDS,
-                              priors=priors, n_eff=np.asarray(n_effs),
-                              hyper=hyp)
+                              priors=priors, n_eff=stack(n_effs), hyper=hyp)
+        assert sweep.TRACE_COUNT[0] - before <= 1
         for i, ((a, g), b) in enumerate(zip(cells, (6.6e-4, 1.9e-3))):
             res = evaluate.run(cfg, env, b, seeds=SEEDS, priors=priors,
                                n_eff=n_effs[i],
@@ -304,6 +292,11 @@ class TestHyperShift:
         np.testing.assert_array_equal(res.arms, flat.arms)
 
 
+# alpha 0.05 in the first of two conditions, the default in the second.
+_ALPHA_PER_CONDITION = HyperParams(
+    alpha=np.asarray([0.05, HyperParams().alpha], np.float32))
+
+
 class TestPallasUnderFabricVmap:
     """ROADMAP item: validate the Pallas ``linucb_score`` backend under
     the fabric's flattened (condition x seed) vmap axis — including
@@ -345,9 +338,8 @@ class TestPallasUnderFabricVmap:
         fabric grid (wide vmap axis) reproduces per-condition looped runs
         of the SAME backend bit-for-bit, with hypers on the grid axis."""
         cfg = RouterConfig(max_arms=4, backend="pallas")
-        edits = (sweep.hyper_edit(alpha=0.05), None)
         grid = sweep.run_grid(cfg, env, (6.6e-4, 1.9e-3), seeds=SEEDS,
-                              batch_size=16, condition_edits=edits)
+                              batch_size=16, hyper=_ALPHA_PER_CONDITION)
         a = evaluate.run(cfg, env, 6.6e-4, seeds=SEEDS, batch_size=16,
                          hyper=HyperParams(alpha=0.05))
         b = evaluate.run(cfg, env, 1.9e-3, seeds=SEEDS, batch_size=16)
@@ -358,13 +350,12 @@ class TestPallasUnderFabricVmap:
         """Backend equivalence holds inside the fabric: same grid, both
         backends, per-decision agreement within the contract's reach
         (scores differ <= EQUIV_TOL, so argmax flips are rare)."""
-        edits = (sweep.hyper_edit(alpha=0.05), None)
         grids = {}
         for bk in ("jnp", "pallas"):
             cfg = RouterConfig(max_arms=4, backend=bk)
             grids[bk] = sweep.run_grid(
                 cfg, env, (6.6e-4, 1.9e-3), seeds=SEEDS, batch_size=16,
-                condition_edits=edits)
+                hyper=_ALPHA_PER_CONDITION)
         agree = (grids["jnp"].arms == grids["pallas"].arms).mean()
         assert agree > 0.99, f"backends diverged: {agree:.3f} agreement"
 

@@ -70,25 +70,6 @@ class TestGridEquivalence:
             res = evaluate.run(CFG, env, b, seeds=SEEDS, batch_size=16)
             _assert_bitwise(grid.condition(i), res)
 
-    def test_condition_edits_stack_state_leaves(self, env):
-        """A non-budget state-leaf axis: pacer enabled vs disabled as a
-        two-condition grid via per-condition pure edits."""
-        import dataclasses
-
-        def disable(st):
-            return dataclasses.replace(
-                st, pacer=dataclasses.replace(
-                    st.pacer, enabled=st.pacer.enabled & False))
-
-        grid = sweep.run_grid(
-            CFG, env, (6.6e-4, 6.6e-4), seeds=SEEDS,
-            condition_edits=(None, disable))
-        on = evaluate.run(CFG, env, 6.6e-4, seeds=SEEDS)
-        off = evaluate.run(CFG, env, 6.6e-4, seeds=SEEDS,
-                           pacer_enabled=False)
-        _assert_bitwise(grid.condition(0), on)
-        _assert_bitwise(grid.condition(1), off)
-
 
 @pytest.mark.usefixtures("no_implicit_transfers", "no_leaked_tracers")
 class TestOneCompiledProgram:
@@ -259,60 +240,22 @@ class TestScenarioParamGrid:
                 CFG, spec, env, b_flat, seeds=SEEDS,
                 scenario_params=ScenarioParams(mult=2.0 * m_flat))
 
-    def test_param_edit_equals_stacked_leaves(self, env):
-        """Per-condition ``param_edit`` entries fold into the same
-        stacked leaves as an explicit (C,) ScenarioParams."""
-        spec = self._price_spec(Param("mult"))
-        budgets = (6.6e-4,) * len(self.MULTS)
-        a = sweep.run_scenario_grid(
-            CFG, spec, env, budgets, seeds=SEEDS,
-            scenario_params=ScenarioParams(
-                mult=np.asarray(self.MULTS, np.float32)))
-        b = sweep.run_scenario_grid(
-            CFG, spec, env, budgets, seeds=SEEDS,
-            condition_edits=[sweep.param_edit(mult=m) for m in self.MULTS])
-        for i in range(len(self.MULTS)):
-            _assert_bitwise(a.condition(i), b.condition(i))
-
-    def test_chained_hyper_and_param_edits(self, env):
-        """Satellite: ``chain_edits(hyper_edit(...), param_edit(...))``
-        puts an (alpha, payload) pair per condition on one fused grid,
+    def test_stacked_hyper_and_payload_axes(self, env):
+        """A (C,) ``hyper`` alpha and a (C,) ``scenario_params`` payload
+        put an (alpha, payload) pair per condition on one fused grid,
         bit-identical to looping run_scenario with the same knobs."""
         cells = ((0.01, 1 / 56), (0.1, 0.3), (0.2, 2.0))
         spec = self._price_spec(Param("mult"))
         grid = sweep.run_scenario_grid(
             CFG, spec, env, (6.6e-4,) * len(cells), seeds=SEEDS,
-            condition_edits=[
-                sweep.chain_edits(sweep.hyper_edit(alpha=a),
-                                  sweep.param_edit(mult=m))
-                for a, m in cells])
+            hyper=HyperParams(
+                alpha=np.asarray([a for a, _ in cells], np.float32)),
+            scenario_params=ScenarioParams(
+                mult=np.asarray([m for _, m in cells], np.float32)))
         for i, (a, m) in enumerate(cells):
             res = evaluate.run_scenario(
                 CFG, self._price_spec(m), env, 6.6e-4, seeds=SEEDS,
                 hyper=HyperParams(alpha=a))
-            _assert_bitwise(grid.condition(i), res)
-
-    def test_param_edit_rejected_on_plain_grid(self, env):
-        with pytest.raises(ValueError, match="run_scenario_grid"):
-            sweep.run_grid(CFG, env, (6.6e-4,), seeds=SEEDS,
-                           condition_edits=[sweep.param_edit(mult=0.5)])
-
-    def test_partial_param_edit_without_base_rejected(self, env):
-        spec = self._price_spec(Param("mult"))
-        with pytest.raises(ValueError, match="no base value"):
-            sweep.run_scenario_grid(
-                CFG, spec, env, (6.6e-4, 6.6e-4), seeds=SEEDS,
-                condition_edits=[sweep.param_edit(mult=0.5), None])
-
-    def test_partial_param_edit_with_base_fallback(self, env):
-        spec = self._price_spec(Param("mult"))
-        grid = sweep.run_scenario_grid(
-            CFG, spec, env, (6.6e-4, 6.6e-4), seeds=SEEDS,
-            scenario_params=ScenarioParams(mult=0.3),
-            condition_edits=[sweep.param_edit(mult=2.0), None])
-        for i, m in enumerate((2.0, 0.3)):
-            res = evaluate.run_scenario(
-                CFG, self._price_spec(m), env, 6.6e-4, seeds=SEEDS)
             _assert_bitwise(grid.condition(i), res)
 
 
@@ -335,14 +278,21 @@ class TestGridGuards:
         with pytest.raises(ValueError, match="seeds is empty"):
             sweep.run_scenario_grid(CFG, self.SPEC, env, BUDGETS, seeds=())
 
-    def test_mismatched_condition_edits(self, env):
-        with pytest.raises(ValueError, match="condition_edits"):
-            sweep.run_grid(CFG, env, BUDGETS, seeds=SEEDS,
-                           condition_edits=[None])
-        with pytest.raises(ValueError, match="condition_edits"):
-            sweep.run_scenario_grid(CFG, self.SPEC, env, BUDGETS,
-                                    seeds=SEEDS, condition_edits=[None, None])
-
+    @pytest.mark.parametrize("stack", ["hyper", "n_eff"])
+    @pytest.mark.parametrize("entry", ["run_grid", "run_scenario_grid"])
+    def test_stack_of_neither_length_rejected(self, entry, stack, env):
+        """A hyper leaf or n_eff of C + 1 values is neither a (C,)
+        per-condition nor a (C*S,) per-element stack: refused, not
+        broadcast or truncated."""
+        bad = np.full(len(BUDGETS) + 1, 0.05, np.float32)
+        kw = ({"hyper": HyperParams(alpha=bad)} if stack == "hyper"
+              else {"n_eff": bad})
+        with pytest.raises(ValueError, match=stack):
+            if entry == "run_grid":
+                sweep.run_grid(CFG, env, BUDGETS, seeds=SEEDS, **kw)
+            else:
+                sweep.run_scenario_grid(CFG, self.SPEC, env, BUDGETS,
+                                        seeds=SEEDS, **kw)
 
 class TestPriceChangeConcatStrict:
     """Regression (satellite): a PriceChange protocol composes with

@@ -243,11 +243,11 @@ def test_grid_programs_carry_their_names(kind, env, envs, monkeypatch):
     seen = []
     launch = sweep._launch_and_read
 
-    def record(fn, operands, C, S):
-        seen.append((fn, [jax.ShapeDtypeStruct(a.shape, a.dtype)
-                          for a in jax.tree.leaves(operands)],
+    def record(program, operands, C, S):
+        seen.append((program(), [jax.ShapeDtypeStruct(a.shape, a.dtype)
+                                 for a in jax.tree.leaves(operands)],
                      jax.tree.structure(operands)))
-        return launch(fn, operands, C, S)
+        return launch(program, operands, C, S)
 
     monkeypatch.setattr(sweep, "_launch_and_read", record)
     _grid(kind, env, envs)

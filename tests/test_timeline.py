@@ -15,7 +15,7 @@ from repro.core.scenario import (
     QualityShift, ScenarioParams, ScenarioSpec, Timeline, TrafficMixShift,
     retime,
 )
-from repro.core.types import RouterConfig
+from repro.core.types import HyperParams, RouterConfig
 from tests.trace_guard import assert_traces
 
 CFG = RouterConfig(max_arms=4)
@@ -28,6 +28,13 @@ def env():
     b = simulator.make_benchmark(
         seed=0, splits={"train": 256, "val": 32, "test": 200})
     return b.test
+
+
+@pytest.fixture(scope="module")
+def priors():
+    train = simulator.make_benchmark(
+        seed=0, splits={"train": 256, "val": 32, "test": 200}).train
+    return evaluate.fit_warmup_priors(CFG, train)
 
 
 @pytest.fixture(scope="module")
@@ -292,6 +299,41 @@ class TestGridTimelines:
             np.testing.assert_array_equal(grid.lams[ci, si, :h],
                                           ref.lams[0])
 
+    @pytest.mark.parametrize("stack", ["hyper", "n_eff"])
+    def test_per_element_hyper_and_neff_stacks(self, stack, env, priors):
+        """(C*S,) per-element hyper and n_eff stacks on the Monte Carlo
+        path: each element bit-identical to ``run_scenario`` on its
+        retimed spec at its own value."""
+        seeds = (0, 1)
+        tls = [Timeline((25, 70)), Timeline((60, 90), horizon=100),
+               Timeline((10, 20)), Timeline((0, 110), horizon=112)]
+        if stack == "hyper":
+            values = np.asarray([0.01, 0.3, 0.8, 0.05], np.float32)
+            grid_kw = dict(hyper=HyperParams(alpha=values))
+
+            def one(v):
+                return dict(hyper=dataclasses.replace(CFG.hyper, alpha=v))
+        else:
+            values = np.asarray([10.0, 100.0, 400.0, 1164.0], np.float32)
+            grid_kw = dict(priors=priors, n_eff=values)
+
+            def one(v):
+                return dict(priors=priors, n_eff=v)
+
+        grid = sweep.run_scenario_grid(CFG, self.SPEC, env, self.BUDGETS,
+                                       seeds=seeds, timelines=tls, **grid_kw)
+        S = len(seeds)
+        for i, tl in enumerate(tls):
+            ci, si = divmod(i, S)
+            r = retime(self.SPEC, tl)
+            ref = evaluate.run_scenario(CFG, r, env, self.BUDGETS[ci],
+                                        seeds=(seeds[si],),
+                                        **one(float(values[i])))
+            h = r.horizon
+            for name in ("arms", "rewards", "costs", "lams"):
+                np.testing.assert_array_equal(
+                    getattr(grid, name)[ci, si, :h], getattr(ref, name)[0])
+
     def test_wrong_timeline_count_rejected(self, env):
         with pytest.raises(ValueError, match="timelines"):
             sweep.run_scenario_grid(CFG, self.SPEC, env, self.BUDGETS,
@@ -299,11 +341,12 @@ class TestGridTimelines:
                                     timelines=[Timeline((25, 70))] * 3)
 
     def test_composes_with_chunk_and_edits(self, env):
-        """Timelines x chunked scan x per-condition hyper edits: the
-        chunked program is bit-identical to the unchunked one."""
+        """Timelines x chunked scan x a per-condition (C,) hyper stack:
+        the chunked program is bit-identical to the unchunked one."""
         tls = [Timeline((25, 70)), Timeline((60, 90))]
-        edits = [sweep.hyper_edit(alpha=0.8), None]
-        kw = dict(seeds=(0, 1), timelines=tls, condition_edits=edits)
+        hyper = HyperParams(
+            alpha=np.asarray([0.8, CFG.hyper.alpha], np.float32))
+        kw = dict(seeds=(0, 1), timelines=tls, hyper=hyper)
         plain = sweep.run_scenario_grid(CFG, self.SPEC, env, self.BUDGETS,
                                         **kw)
         chunked = sweep.run_scenario_grid(CFG, self.SPEC, env, self.BUDGETS,
