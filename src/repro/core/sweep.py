@@ -32,7 +32,10 @@ one jitted, device-sharded call:
     *inside* the one compiled program, so wide grids whose per-step
     working set spills the last-level cache (the knee grid's N ~ 720
     elements carry ~30 MB of live state) trade embarrassing parallelism
-    for locality without changing a single result bit.
+    for locality without changing a single result bit. Left None on one
+    TPU it defaults to the largest chunk whose state carry fits the
+    chip's on-chip memory (``default_chunk``), so the step's statistics
+    never stream through HBM; elsewhere the whole grid is one chunk.
 
 Hyper-parameters are state leaves too (DESIGN.md §9): ``RouterState``
 carries a ``HyperParams`` pytree, so a whole (α, γ) grid stacks on the
@@ -278,6 +281,64 @@ def _n_chunks(n: int, chunk_size) -> int:
     return n // chunk_size
 
 
+# On-chip budget of one chunk's scan carry, by ``device_kind``. A TPU
+# v5e TensorCore has 128 MiB of vector memory (VMEM, XLA's memory space
+# S(1)); the carry takes half, the rest holding the step's temporaries.
+# Compiled for a described v5e at d = 26 and 8 slots (273,484 B an
+# element, tiled): 160 elements (43.8 MB) keep every state leaf in S(1),
+# and at 256 (70.0 MB) one stack's copy in the step falls out to HBM.
+ONCHIP_CARRY_BYTES = {"TPU v5 lite": 64 * 2 ** 20}
+
+
+def _tiled_nbytes(shape, dtype) -> int:
+    """Bytes of one grid element's leaf in a chunk's state stack, its
+    last two dims padded to the (8, 128) tile: a rank-1 leaf pads to
+    128 lanes (the element axis is its sublane), a scalar is one word."""
+    shape = tuple(int(s) for s in shape)
+    if len(shape) >= 2:
+        shape = shape[:-2] + (-(-shape[-2] // 8) * 8,
+                              -(-shape[-1] // 128) * 128)
+    elif shape:
+        shape = (-(-shape[0] // 128) * 128,)
+    return int(np.prod(shape)) * np.dtype(dtype).itemsize
+
+
+def default_chunk(n: int, leaves, device_kind: str, n_devices: int = 1,
+                  chunk_size: Optional[int] = None) -> Optional[int]:
+    """The elements per chunk for an ``n``-element grid whose elements
+    carry state ``leaves`` (per-element shapes and dtypes): the largest
+    divisor of ``n`` whose tile-padded carry fits the device kind's
+    on-chip budget (``ONCHIP_CARRY_BYTES``), so the step's statistics
+    stay on chip instead of streaming through HBM every step.
+
+    An explicit ``chunk_size`` wins. None (one chunk, the whole grid
+    live) where the grid fits whole, on a kind with no budget (the CPU
+    among them), on more than one device (the chunk reshape would cut
+    across the sharded axis), and where no divisor reaches a quarter of
+    the fitting size (a prime ``n`` never runs one element at a time)."""
+    if chunk_size is not None:
+        return chunk_size
+    budget = ONCHIP_CARRY_BYTES.get(device_kind)
+    if budget is None or n_devices != 1:
+        return None
+    fits = budget // sum(_tiled_nbytes(l.shape, l.dtype) for l in leaves)
+    if fits < 1 or n <= fits:
+        return None
+    c = fit_chunk(n, fits)
+    return c if 4 * c >= fits else None
+
+
+def grid_chunk(states, chunk_size: Optional[int] = None) -> Optional[int]:
+    """``default_chunk`` for a placed state stack (arrays, or shapes
+    with a sharding): its grid size, per-element leaves and devices."""
+    n = int(states.t.shape[0])
+    devices = states.t.sharding.device_set
+    leaves = [jax.ShapeDtypeStruct(l.shape[1:], l.dtype)
+              for l in jax.tree.leaves(states)]
+    return default_chunk(n, leaves, next(iter(devices)).device_kind,
+                         len(devices), chunk_size)
+
+
 def fit_chunk(n: int, chunk_size: int) -> int:
     """The largest divisor of ``n`` that is <= ``chunk_size`` (always
     >= 1) — the convenience for callers whose grid size is not known to
@@ -293,13 +354,15 @@ def _chunk_wrap(vm, n_chunks: int, scan_in):
 
     A wide grid's per-step working set is N x (per-element state), which
     for the knee grid's N ~ 720 elements spills the CPU last-level cache
-    (~30 MB live vs ~24 MB L3; see benchmarks/results/knee.json). The
-    wrapper reshapes every (N, ...) operand to (n_chunks, N/n_chunks,
-    ...), runs the chunks *sequentially* under ``lax.scan`` and flattens
-    the stacked outputs back — the live set shrinks by n_chunks while
-    the whole grid stays ONE compiled program. vmap is elementwise over
-    the grid axis, so per-element math is untouched and results stay
-    bit-identical to the unchunked fabric (pinned in tests/test_sweep.py).
+    (~30 MB live vs ~24 MB L3; see benchmarks/results/knee.json), and
+    on a TPU a stack past on-chip memory is streamed through HBM, and
+    relaid, every step (``default_chunk``). The wrapper reshapes every
+    (N, ...) operand to (n_chunks, N/n_chunks, ...), runs the chunks
+    *sequentially* under ``lax.scan`` and flattens the stacked outputs
+    back — the live set shrinks by n_chunks while the whole grid stays
+    ONE compiled program. vmap is elementwise over the grid axis, so
+    per-element math is untouched and results stay bit-identical to the
+    unchunked fabric (pinned in tests/test_sweep.py).
     ``scan_in`` flags which trailing operands carry the grid axis
     (chunked with the states) vs being shared across elements (closed
     over, replicated to every chunk).
@@ -419,7 +482,9 @@ def run_grid(
     chunk-by-chunk inside the same compiled program, shrinking the
     per-step working set so wide grids stop spilling the last-level
     cache (DESIGN.md §11). Results are bit-identical to the unchunked
-    fabric. ``None`` (default) keeps the whole grid live.
+    fabric. ``None`` (default) takes ``default_chunk``'s: on one TPU the
+    largest divisor whose state carry fits on chip, elsewhere (and
+    where the whole grid fits) the whole grid live.
 
     ``tenant_tables`` + ``tenant_ids`` put the tenant plane on the grid
     (DESIGN.md §15): tables with (T,) shared, (C, T) per-condition or
@@ -481,14 +546,13 @@ def run_grid(
                 states, (xs, rmat, cmat), stream_axes, C, devices,
                 extras=extras)
 
-        def program():
+        def program(n_chunks):
             cached = (_cached_grid_fn if tenant_ids is None
                       else _cached_grid_fn_tenants)
-            return cached(cfg.statics, stream_axes, batch_size,
-                          _n_chunks(C * S, chunk_size))
+            return cached(cfg.statics, stream_axes, batch_size, n_chunks)
 
         finals, (arms, r, c, lam) = _launch_and_read(
-            program, (states, *streams, *extras), C, S)
+            program, (states, *streams, *extras), C, S, chunk_size)
         res = GridResult(budgets=budgets, seeds=seeds, arms=arms, rewards=r,
                          costs=c, lams=lam)
     if return_states:
@@ -496,15 +560,20 @@ def run_grid(
     return res
 
 
-def _launch_and_read(program, operands, C: int, S: int):
+def _launch_and_read(program, operands, C: int, S: int,
+                     chunk_size: Optional[int] = None):
     """Run a compiled grid program and read its per-step traces back as
-    (C, S, T) host arrays: ``sweep.launch`` (``program()``, which looks
-    the cached program up, and its dispatch), ``sweep.wait`` (the device
-    running it) and ``sweep.readback`` (the copies, with their
+    (C, S, T) host arrays: ``sweep.launch`` (the chunk, from
+    ``chunk_size`` or ``grid_chunk``'s default for the placed states and
+    recorded as its ``chunk`` argument; ``program(n_chunks)``, which
+    looks the cached program up; its dispatch), ``sweep.wait`` (the
+    device running it) and ``sweep.readback`` (the copies, with their
     ``d2h_bytes``). Returns (final states, (arms, rewards, costs,
     lams))."""
-    with jax.profiler.TraceAnnotation("sweep.launch"):
-        finals, outs = program()(*operands)
+    with jax.profiler.TraceAnnotation("sweep.launch") as span:
+        n_chunks = _n_chunks(C * S, grid_chunk(operands[0], chunk_size))
+        span.set_metadata(chunk=C * S // n_chunks)
+        finals, outs = program(n_chunks)(*operands)
     with jax.profiler.TraceAnnotation("sweep.wait"):
         jax.block_until_ready(outs)
     with jax.profiler.TraceAnnotation("sweep.readback") as span:
@@ -670,7 +739,7 @@ def run_scenario_grid(
 
     ``chunk_size`` scans the flattened grid chunk-by-chunk inside the
     compiled program exactly as in ``run_grid`` (bit-identical results,
-    bounded per-step working set).
+    bounded per-step working set), with the same on-chip default.
 
     ``timelines`` puts the spec's event *times* and effective horizon on
     the condition axis (DESIGN.md §12): one shared
@@ -714,14 +783,13 @@ def run_scenario_grid(
         states, streams, pstack, extras = _shard_grid(
             states, stacks, 0, C, devices, pstack, extras=extras)
 
-        def program():
+        def program(n_chunks):
             cached = (_cached_scenario_grid_fn if timelines is None
                       else _cached_timeline_grid_fn)
-            return cached(cfg, spec, env, batch_size,
-                          _n_chunks(C * S, chunk_size))
+            return cached(cfg, spec, env, batch_size, n_chunks)
 
         finals, (arms, r, c, lam) = _launch_and_read(
-            program, (states, *streams, pstack, *extras), C, S)
+            program, (states, *streams, pstack, *extras), C, S, chunk_size)
         if timelines is None:
             bounds = spec.bounds
         else:

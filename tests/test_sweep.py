@@ -466,3 +466,53 @@ class TestChunkedFabric:
         assert sweep.fit_chunk(9, 100) == 9
         assert sweep.fit_chunk(7, 3) == 1
         assert sweep.fit_chunk(12, 12) == 12
+
+
+class TestDefaultChunk:
+    """The fabric's default chunk (``sweep.default_chunk``): on one TPU
+    v5e the largest divisor of the grid whose tile-padded state carry
+    fits the on-chip budget; elsewhere the whole grid, one chunk."""
+
+    V5E = "TPU v5 lite"
+
+    @pytest.fixture(scope="class")
+    def leaves(self, env):
+        """Per-element state leaves at the paper's statics (d = 26, 8
+        slots, 20 forced pulls), as ``paper3`` and ``paper3_shifts``."""
+        cfg = RouterConfig(d=26, max_arms=8, forced_pulls=20)
+        states = evaluate.make_states(cfg, env, 6.6e-4, (0,), active_arms=3)
+        return [jax.ShapeDtypeStruct(l.shape[1:], l.dtype)
+                for l in jax.tree.leaves(states)]
+
+    def test_paper3_grid_stays_one_chunk(self, leaves):
+        chunk = sweep.default_chunk(160, leaves, self.V5E)
+        assert sweep._n_chunks(160, chunk) == 1
+
+    def test_paper3_mc1024_splits_into_on_chip_chunks(self, leaves):
+        # 128 elements carry 35.0 MB tiled, 256 carry 70.0 MB: the
+        # compile test confirms 128 keeps A and A^-1 in S(1).
+        assert sweep.default_chunk(1024, leaves, self.V5E) == 128
+
+    def test_prime_grid_stays_unchunked(self, leaves):
+        assert sweep.default_chunk(1021, leaves, self.V5E) is None
+
+    @pytest.mark.parametrize("kind,n_devices", [
+        ("cpu", 1), ("TPU v0 unknown", 1), ("TPU v5 lite", 2)])
+    def test_no_default_off_one_known_chip(self, leaves, kind, n_devices):
+        assert sweep.default_chunk(1024, leaves, kind, n_devices) is None
+
+    @pytest.mark.parametrize("kind", ["cpu", "TPU v5 lite"])
+    def test_explicit_chunk_size_wins(self, leaves, kind):
+        assert sweep.default_chunk(1024, leaves, kind, 1, 512) == 512
+        assert sweep.default_chunk(1024, leaves, kind, 2, 4) == 4
+
+    def test_placed_cpu_grid_keeps_one_chunk(self, env):
+        states = jax.device_put(evaluate.make_states(
+            CFG, env, 6.6e-4, tuple(range(4))))
+        assert sweep.grid_chunk(states) is None
+        assert sweep.grid_chunk(states, 2) == 2
+
+    def test_tiled_bytes(self):
+        assert sweep._tiled_nbytes((8, 26, 26), np.float32) == 8 * 32 * 128 * 4
+        assert sweep._tiled_nbytes((8,), np.int32) == 128 * 4
+        assert sweep._tiled_nbytes((), np.float32) == 4

@@ -185,6 +185,18 @@ def test_scenario_grid_byte_counters_equal_the_shapes(env, tmp_path):
     assert got["sweep.readback"][0].args == {"d2h_bytes": out}
 
 
+@pytest.mark.parametrize("chunk_size", [None, 2])
+def test_launch_records_the_chunk(chunk_size, env, tmp_path):
+    """``sweep.launch`` records the elements per chunk the call ran with:
+    the whole grid by default on the CPU, else the ``chunk_size``."""
+    n = len(BUDGETS) * len(SEEDS)
+    with profiled(tmp_path) as spans:
+        sweep.run_scenario_grid(CFG, SPEC, env, BUDGETS, seeds=SEEDS,
+                                timelines=TIMELINES, chunk_size=chunk_size)
+    got = _by_name(spans)
+    assert got["sweep.launch"][0].args == {"chunk": chunk_size or n}
+
+
 def _tenant_args(env):
     tables = tenancy.stack_tables([tenancy.make_table([2e-4, 3e-4]),
                                    tenancy.make_table([4e-4, 5e-4])])
@@ -243,11 +255,11 @@ def test_grid_programs_carry_their_names(kind, env, envs, monkeypatch):
     seen = []
     launch = sweep._launch_and_read
 
-    def record(program, operands, C, S):
-        seen.append((program(), [jax.ShapeDtypeStruct(a.shape, a.dtype)
-                                 for a in jax.tree.leaves(operands)],
+    def record(program, operands, C, S, chunk_size=None):
+        seen.append((program(1), [jax.ShapeDtypeStruct(a.shape, a.dtype)
+                                  for a in jax.tree.leaves(operands)],
                      jax.tree.structure(operands)))
-        return launch(program, operands, C, S)
+        return launch(program, operands, C, S, chunk_size)
 
     monkeypatch.setattr(sweep, "_launch_and_read", record)
     _grid(kind, env, envs)

@@ -6,6 +6,7 @@ sweep fabric's payload/hyper/chunk axes. Plus the Monte Carlo layer on
 top (sampling validity, metric shapes)."""
 import dataclasses
 
+import jax
 import numpy as np
 import pytest
 
@@ -359,6 +360,38 @@ class TestGridTimelines:
             seeds=(0, 1),
             hyper=dataclasses.replace(CFG.hyper, alpha=0.8))
         _assert_bitwise(ref, plain.condition(0))
+
+    def test_default_chunk_is_bit_identical(self, env4):
+        """The chunk the fabric picks on one TPU v5e for 512 elements at
+        the paper's statics, passed here explicitly, gives the bits of
+        the whole grid run as one chunk: a silent price cut, a quality
+        shift and a cold AddArm with forced pulls, each element on its
+        own timeline, every third horizon cut short and padded."""
+        cfg = RouterConfig(d=26, max_arms=8, forced_pulls=5)
+        spec = ScenarioSpec(horizon=48, events=(
+            PriceChange(8, GEMINI, 1 / 56), QualityShift(8, MISTRAL, 0.75),
+            AddArm(8, 3)), stream_seed_base=950, init_active=3)
+        budgets, seeds = (6.6e-4, 1.9e-3), tuple(range(256))
+        n = len(budgets) * len(seeds)
+        steps = np.random.default_rng(0).integers(4, 32, size=(n, 3))
+        tls = [Timeline(tuple(int(t) for t in s),
+                        horizon=40 if i % 3 == 0 else None)
+               for i, s in enumerate(steps)]
+        one = evaluate.make_states(cfg, env4, budgets[0], (0,),
+                                   active_arms=3)
+        chunk = sweep.default_chunk(
+            n, [jax.ShapeDtypeStruct(l.shape[1:], l.dtype)
+                for l in jax.tree.leaves(one)], "TPU v5 lite")
+        assert 1 < chunk < n
+        kw = dict(seeds=seeds, timelines=tls, return_states=True)
+        whole, whole_st = sweep.run_scenario_grid(
+            cfg, spec, env4, budgets, chunk_size=n, **kw)
+        got, got_st = sweep.run_scenario_grid(
+            cfg, spec, env4, budgets, chunk_size=chunk, **kw)
+        _assert_bitwise(whole, got)
+        assert (whole.arms == 3).any(axis=-1).all()   # forced pulls ran
+        for a, b in zip(jax.tree.leaves(whole_st), jax.tree.leaves(got_st)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
     def test_composes_with_param_payloads(self, env):
         """A Param payload stack and a timeline axis ride together."""

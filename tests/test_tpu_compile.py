@@ -5,6 +5,7 @@ mode cannot). The topology is described inside a fixture, never at
 import, and the persistent compile cache is off around these compiles
 (a compile for a described chip cannot be read back from it)."""
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -91,13 +92,76 @@ def test_linucb_step_vmapped_compiles(one_chip, no_persistent_cache):
     assert "tpu_custom_call" in text
 
 
+def _computations(text):
+    """HLO text -> {computation name: its instruction lines}."""
+    comps, body = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            body = comps.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            body = None
+        elif body is not None:
+            body.append(line.strip())
+    return comps
+
+
+def _step_scan(text, stack):
+    """The ``while`` whose carry holds ``stack`` (an HLO shape such as
+    ``f32[128,8,26,26]``): (its carry's ``stack`` types, its body)."""
+    comps = _computations(text)
+    for line in text.splitlines():
+        if " while(" in line and stack + "{" in line:
+            carry = re.findall(re.escape(stack) + r"\{[^}]*\}", line)
+            body = re.search(r"body=%([^,\s]+)", line).group(1)
+            return carry, comps[body]
+    raise AssertionError(f"no while carries {stack}")
+
+
+def _relayout_copies(body, d):
+    """Copies in ``body`` that move an f32[..., d, d] stack to another
+    layout (the operand's minor-to-major order differs)."""
+    types = {}
+    for ins in body:
+        m = re.match(r"(?:ROOT )?%(\S+) = (\S+\{[^}]*\})", ins)
+        if m:
+            types[m.group(1)] = m.group(2)
+
+    def order(t):
+        return t.split("{", 1)[1].split(":", 1)[0].rstrip("}")
+
+    out = []
+    for ins in body:
+        m = re.match(r"(?:ROOT )?%(\S+) = (f32\[[^\]]*\]\{[^}]*\}) "
+                     r"copy\(%([^)]+)\)", ins)
+        if m and m.group(2).split("]")[0].count(str(d)) >= 2:
+            src = types.get(m.group(3))
+            if src is None or order(src) != order(m.group(2)):
+                out.append(ins[:120])
+    return out
+
+
+def _paper3_states(cfg, env, train, sharding, n):
+    """The warm-started state stack of ``n`` elements as shapes."""
+    from repro.core import evaluate
+
+    priors = evaluate.fit_warmup_priors(cfg, train)
+    priors = list(priors) + [None] * (env.k - len(priors))
+    states = evaluate.make_states(cfg, env, 6.6e-4, (0,), priors=priors,
+                                  n_eff=1164.0, active_arms=3)
+    return jax.tree.map(lambda l: jax.ShapeDtypeStruct(
+        (n,) + l.shape[1:], l.dtype, sharding=sharding), states)
+
+
 def test_timeline_grid_program_compiles(one_chip, no_persistent_cache):
     """The masked timeline grid program of the ``paper3_mc1024`` cell at
     its own size: 1,024 elements x 1,824 steps, 8 arm slots, d = 26, a
     silent price cut, a quality shift and a cold ``AddArm`` with forced
     pulls, each event's step an operand of every element. The cold arm's
-    inverse is closed form, so the program holds no LU custom call."""
-    from repro.core import evaluate, scenario, simulator, sweep
+    inverse is closed form, so the program holds no LU custom call. The
+    fabric's default chunk on one v5e (128 elements) keeps each chunk's
+    A and A^-1 on chip, in S(1), and the step relays no stack."""
+    from repro.core import scenario, simulator, sweep
     from repro.core.scenario import (AddArm, PriceChange, QualityShift,
                                      ScenarioSpec)
     from repro.core.types import RouterConfig
@@ -110,18 +174,18 @@ def test_timeline_grid_program_compiles(one_chip, no_persistent_cache):
     spec = ScenarioSpec(horizon=T, events=(
         PriceChange(152, 2, 1 / 56), QualityShift(152, 1, 0.75),
         AddArm(152, 3)), init_active=3)
-    fn = sweep._cached_timeline_grid_fn(cfg, spec, env, None)
-    priors = evaluate.fit_warmup_priors(cfg, b.train) + [None]
-    states = evaluate.make_states(cfg, env, 6.6e-4, (0,), priors=priors,
-                                  n_eff=1164.0, active_arms=3)
+    states = _paper3_states(cfg, env, b.train, one_chip, N)
+    chunk = sweep.grid_chunk(states)
+    assert chunk == 128
+    fn = sweep._cached_timeline_grid_fn(cfg, spec, env, None,
+                                        sweep._n_chunks(N, chunk))
     params = scenario.ScenarioParams(**scenario.auto_param_values(spec))
 
     def spec_of(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     operands = (
-        jax.tree.map(lambda l: spec_of((N,) + l.shape[1:], l.dtype), states),
-        spec_of((N, T, d)), spec_of((N, T, 8)), spec_of((N, T, 8)),
+        states, spec_of((N, T, d)), spec_of((N, T, 8)), spec_of((N, T, 8)),
         jax.tree.map(lambda l: spec_of((N,) + l.shape), params),
         spec_of((N, len(spec.events)), jnp.int32), spec_of((N,), jnp.int32))
     compiled = fn.lower(*operands).compile()
@@ -132,6 +196,25 @@ def test_timeline_grid_program_compiles(one_chip, no_persistent_cache):
     for target in ("LuDecompositionBlock", "InvertDiagBlocksLowerTriangular",
                    "InvertDiagBlocksUpperTriangular"):
         assert target not in text, target
+    # The step scan carries the chunk's A and A^-1 in on-chip memory and
+    # moves neither between layouts.
+    carry, body = _step_scan(text, f"f32[{chunk},8,{d},{d}]")
+    assert len(carry) >= 2 and all(":T(8,128)S(1)}" in c for c in carry), \
+        carry
+    assert _relayout_copies(body, d) == []
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes) < 16e9
+
+
+def test_paper3_grid_default_chunk_is_one(one_chip):
+    """The ``paper3_grid`` cell's 160 elements fit on chip whole: its
+    grid program stays one chunk on one v5e."""
+    from repro.core import simulator, sweep
+    from repro.core.types import RouterConfig
+
+    b = simulator.make_benchmark(
+        seed=0, splits={"train": 128, "val": 16, "test": 64})
+    cfg = RouterConfig(d=26, max_arms=8)
+    states = _paper3_states(cfg, b.test, b.train, one_chip, 160)
+    assert sweep._n_chunks(160, sweep.grid_chunk(states)) == 1
